@@ -41,4 +41,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import configure_compile_cache
+    configure_compile_cache()
     main()

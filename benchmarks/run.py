@@ -144,4 +144,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import configure_compile_cache
+    configure_compile_cache()
     main()

@@ -4,12 +4,11 @@ bit-packed partition broadcast, plus the single-process simulation backend
 with a mid-training worker failure.
 
     PYTHONPATH=src python examples/distributed_forest.py
-(spawns its own 8 placeholder devices; run unchanged on a real 256-chip pod)
+
+The mesh is built from the devices that are present: the four chips of a
+TPU host give a 2x2 grid. On a CPU host, give it placeholder devices with
+XLA_FLAGS=--xla_force_host_platform_device_count=8.
 """
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
 import jax
 import numpy as np
 
@@ -24,8 +23,11 @@ y = (rng.random(N) < 1 / (1 + np.exp(-logit))).astype(np.float64)
 
 cfg = DistGBTConfig(max_depth=5, n_bins=64, num_trees=20)
 
-print("== 2-D grid training (2 'data' x 4 'model' workers) ==")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+n_dev = len(jax.devices())
+n_model = 2 if n_dev % 2 == 0 else 1       # F=16 splits over 1 or 2 shards
+print(f"== 2-D grid training ({n_dev // n_model} 'data' x {n_model} 'model' "
+      "workers) ==")
+mesh = jax.make_mesh((n_dev // n_model, n_model), ("data", "model"))
 model = DistributedGBT(cfg, mesh).fit(codes, y)
 acc = ((model.predict_scores(codes) > 0) == y).mean()
 print(f"train accuracy: {acc:.4f} over {len(model.trees)} trees")

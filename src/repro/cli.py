@@ -421,4 +421,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import configure_compile_cache
+    configure_compile_cache()
     main()

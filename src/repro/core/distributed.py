@@ -30,7 +30,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.obs import build_training_logs, trace, validate_training_logs
 from repro.core.tree import Forest, empty_forest
@@ -97,7 +96,9 @@ class DistGBTConfig:
     num_trees: int = 20
     data_axis: str = "data"
     model_axis: str = "model"
-    hist_impl: str = "ref"   # ref | pallas (kernels/histogram)
+    # kernels/histogram impl: "auto" is the kernel on a TPU and the jnp
+    # reference elsewhere; "pallas" | "interpret" | "ref" force one
+    hist_impl: str = "auto"
 
 
 def make_level_step(mesh: Mesh, cfg: DistGBTConfig, n_nodes: int, F_local: int):
@@ -144,8 +145,19 @@ def make_level_step(mesh: Mesh, cfg: DistGBTConfig, n_nodes: int, F_local: int):
     specs_in = (P(cfg.data_axis, cfg.model_axis), P(cfg.data_axis, None),
                 P(cfg.data_axis))
     specs_out = (P(), P(), P(), P(cfg.data_axis), P())
-    return jax.jit(shard_map(level, mesh=mesh, in_specs=specs_in,
-                             out_specs=specs_out, check_rep=False))
+    return jax.jit(jax.shard_map(level, mesh=mesh, in_specs=specs_in,
+                                 out_specs=specs_out, check_vma=False))
+
+
+@jax.jit
+def _route(valid, go, node_of):
+    """Descend one level: examples of a validly split node take their go
+    bit, the rest go left. The per-node ``valid`` table is looked up by a
+    one-hot compare rather than a gather, so the result keeps the
+    data-sharded layout of ``node_of`` on any mesh."""
+    own = node_of[:, None] == jnp.arange(valid.shape[0])[None, :]
+    go = jnp.where((own & valid[None, :]).any(axis=1), go, 0)
+    return jnp.where(node_of >= 0, node_of * 2 + go, node_of)
 
 
 # =====================================================================
@@ -164,9 +176,7 @@ def grow_tree_complete(level_fns, codes_sh, stats_sh, node_of0, cfg: DistGBTConf
         feats.append(np.asarray(f))
         bins.append(np.asarray(b))
         gains.append(np.asarray(g))
-        valid = np.isfinite(np.asarray(g))
-        go = jnp.where(jnp.asarray(valid)[node_of.clip(0)], go, 0)
-        node_of = jnp.where(node_of >= 0, node_of * 2 + go, node_of)
+        node_of = _route(jnp.isfinite(g), go, node_of)
     # final per-leaf [g, h, n]: one more psum'd histogram at leaf granularity.
     # hist is per-model-shard (its own features); summing the BINS of any one
     # feature column yields the per-node stat totals, identical on all shards.
@@ -305,6 +315,7 @@ class DistributedGBT:
         self.trees = []
 
         from repro.core.rf import training_data_fingerprint
+        from repro.kernels.histogram import ops as hist_ops
         from repro.train.checkpoint import open_session
         sess = open_session(checkpoint, self._train_config(task),
                             training_data_fingerprint(codes, y))
@@ -349,7 +360,10 @@ class DistributedGBT:
         self.training_logs = build_training_logs(
             learner="distributed_gbt", num_trees=len(self.trees),
             resilience=sess.events if sess is not None else None,
-            interrupted=interrupted)
+            interrupted=interrupted,
+            extra={"devices": sorted(d.id for d in
+                                     codes_d.sharding.device_set),
+                   "hist_impl": hist_ops.resolve_impl(cfg.hist_impl)})
         return self
 
     def predict_scores(self, codes: np.ndarray) -> np.ndarray:

@@ -105,7 +105,7 @@ class GradientBoostedTreesLearner(Learner):
                           engine=hp.growth_engine,
                           histogram_backend=hp.histogram_backend,
                           sampling_key=self.seed & 0xFFFFFFFF)
-        from repro.core.grower import resolve_engine
+        from repro.core.grower import engine_details, resolve_engine
         engine_used, engine_fallback = resolve_engine(gp, td.binned, oblique)
         shrink, l2 = hp.shrinkage, hp.l2_regularization
 
@@ -239,7 +239,8 @@ class GradientBoostedTreesLearner(Learner):
             growth_engine=engine_used, engine_fallback=engine_fallback,
             resilience=sess.events if sess is not None else None,
             interrupted=interrupted,
-            extra={"train_loss": train_losses, "valid_loss": valid_losses})
+            extra={"train_loss": train_losses, "valid_loss": valid_losses,
+                   **engine_details(gp, td.binned, engine_used)})
         return model
 
 

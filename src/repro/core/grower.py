@@ -150,6 +150,24 @@ def resolve_engine(params: GrowthParams, binned: BinnedFeatures | None = None,
     return ("batched", reason) if reason else ("device", None)
 
 
+def engine_details(params: GrowthParams, binned: BinnedFeatures,
+                   engine: str) -> dict:
+    """What the resolved ``engine`` runs on, for ``training_logs``: the
+    device level step's implementation (and why, when "auto" did not pick
+    the fused kernel), or the batched engine's histogram backend."""
+    if engine == "device":
+        from repro.core.grower_device import resolve_impl
+        impl, why = resolve_impl(params.device_impl,
+                                 bool(binned.is_cat.any()))
+        return {"device_impl": impl, "device_impl_reason": why}
+    if engine == "batched":
+        be = resolve_backend(params.histogram_backend)
+        interp = getattr(be, "interpret", False)
+        return {"histogram_backend": be.name + ("_interpret" if interp
+                                                else "")}
+    return {}
+
+
 def grow_tree(forest: Forest, t: int, binned: BinnedFeatures, X_raw: np.ndarray,
               stats: np.ndarray, active: np.ndarray,
               leaf_fn: Callable[[np.ndarray], np.ndarray],

@@ -28,7 +28,8 @@ On TPU the numerical hist+gain pipeline is the fused Pallas kernel
 the jit (the kernel's interpret mode is only for the CI smoke —
 resolve_backend's rule that interpret mode must never be the silent hot path
 applies here too). Datasets with categorical features always use the jnp
-path, which shares ``score_stats`` with the kernel.
+path, which shares ``score_stats`` with the kernel. ``resolve_impl`` names
+the choice and its reason; the learners put both in ``training_logs``.
 """
 from __future__ import annotations
 
@@ -73,12 +74,20 @@ def device_unsupported_reason(params, binned: BinnedFeatures | None = None,
     return None
 
 
-def _resolve_impl(impl: str, has_cat: bool) -> str:
+def resolve_impl(impl: str, has_cat: bool) -> tuple[str, str | None]:
+    """The level step's implementation for a ``device_impl`` request, plus
+    the reason when "auto" resolves to anything but the fused kernel.
+    Explicit requests are honored or refused — never silently swapped."""
     if impl in (None, "auto"):
         import jax
-        if jax.default_backend() == "tpu" and not has_cat:
-            return "pallas"
-        return "jnp"
+        backend = jax.default_backend()
+        if backend != "tpu":
+            return "jnp", (f"jax backend {backend!r} has no TPU; the fused "
+                           "kernel would run in interpret mode")
+        if has_cat:
+            return "jnp", ("categorical features: the fused kernel scans "
+                           "ordered bins only")
+        return "pallas", None
     if impl in ("pallas", "interpret") and has_cat:
         raise YdfError(
             f"device_impl={impl!r} uses the fused numerical kernel, which "
@@ -87,7 +96,7 @@ def _resolve_impl(impl: str, has_cat: bool) -> str:
     if impl not in ("jnp", "pallas", "interpret"):
         raise YdfError(f"Unknown device_impl {impl!r}. Expected one of: "
                        "'auto', 'jnp', 'pallas', 'interpret'.")
-    return impl
+    return impl, None
 
 
 @dataclass(frozen=True)
@@ -155,7 +164,7 @@ def _level_step(cfg: _StepConfig):
             gains, js, sbins = [], [], []
             for k in range(K):
                 gk, jk, bk = fused_split_pallas(
-                    cex[k].astype(jnp.uint8), stats[k], loc[k], w_slots,
+                    cex[k], stats[k], loc[k], w_slots,
                     _B, kind=kind, l2=l2, min_examples=min_ex,
                     interpret=(cfg.impl == "interpret"))
                 gains.append(gk), js.append(jk), sbins.append(bk)
@@ -366,7 +375,7 @@ def grow_trees_device(forest: Forest, ts, binned: BinnedFeatures,
     S = stats_list[0].shape[1]
     M = min(forest.max_nodes, params.max_nodes)
     has_cat = bool(binned.is_cat.any())
-    impl = _resolve_impl(getattr(params, "device_impl", "auto"), has_cat)
+    impl, _ = resolve_impl(getattr(params, "device_impl", "auto"), has_cat)
     one_hot = sp.categorical_algorithm == "ONE_HOT" or (
         sp.stat_kind == "class" and S > 3)
     cfg = _StepConfig(

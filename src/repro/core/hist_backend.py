@@ -8,8 +8,9 @@ Training spends most of its time accumulating per-node gradient histograms
                historical per-stat loop (identical per-bucket accumulation
                order), but a single pass with no per-stat broadcast copies.
   * "pallas" — device path: the one-hot-MXU kernel from
-               ``repro/kernels/histogram`` (DESIGN.md §2.1). Compiled on TPU;
-               interpret-mode (correctness, slow) elsewhere.
+               ``repro/kernels/histogram`` (DESIGN.md §2.1), compiled for the
+               TPU. "pallas_interpret" runs it in interpret mode (CPU
+               correctness path, slow, explicit opt-in only).
 
 ``resolve_backend("auto")`` mirrors the lossy-compilation engine choice in
 ``engines.py``: hardware-aware, pallas only where it is the fast path.
@@ -137,10 +138,9 @@ class PallasHistogramBackend(HistogramBackend):
 
     name = "pallas"
 
-    def __init__(self, interpret: bool | None = None):
-        if interpret is None:
-            import jax
-            interpret = jax.default_backend() != "tpu"
+    def __init__(self, interpret: bool = False):
+        # interpret mode is an explicit opt-in ("pallas_interpret"), never a
+        # silent stand-in for the compiled kernel
         self.interpret = interpret
 
     def build(self, codes, stats, node_of, n_nodes, max_bins=256):

@@ -10,7 +10,12 @@ import numpy as np
 
 from repro.core.api import Learner, Task, YdfError, register_learner
 from repro.core.evaluation import evaluate_predictions
-from repro.core.grower import GrowthParams, grow_trees, resolve_engine
+from repro.core.grower import (
+    GrowthParams,
+    engine_details,
+    grow_trees,
+    resolve_engine,
+)
 from repro.core.hparams import RFHparams
 from repro.obs import build_training_logs, trace
 from repro.core.models import RandomForestModel, prepare_train_data
@@ -200,7 +205,8 @@ class RandomForestLearner(Learner):
             growth_engine=engine_used, engine_fallback=fallback,
             resilience=sess.events if sess is not None else None,
             interrupted=interrupted,
-            extra={"tree_parallelism": block, "oob": oob_logs})
+            extra={"tree_parallelism": block, "oob": oob_logs,
+                   **engine_details(gp, td.binned, engine_used)})
         if hp.compute_oob and hp.bootstrap:
             # everything needed to REGENERATE the per-tree bootstrap bags
             # post-hoc (the multinomial draw is the first consumption of each

@@ -712,8 +712,9 @@ def pack_by_depth(forest: Forest, *, trees_per_block: int | None = None,
     # trim capacity to live nodes, pad to the kernel's node tile
     live = int(forest.n_nodes.max()) if T else 1
     M = max(node_tile, -(-live // node_tile) * node_tile)
-    # feat/thr/lc f32 + cat mask as TWO f32 half-word arrays in-kernel + leaf
-    bytes_per_tree = M * (4 * 3 + 2 * 4 * MASK_WORDS + 4 * O)
+    # the kernel's f32 node table (2 * MASK_WORDS mask-half rows + 4 scalar
+    # rows, padded to 24) and leaf table (out_dim padded to 8 rows)
+    bytes_per_tree = M * 4 * (24 + -(-O // 8) * 8)
     if trees_per_block is None:
         trees_per_block = int(max(1, min(8, vmem_budget_bytes // max(1, bytes_per_tree))))
     TB = min(trees_per_block, max(1, T))

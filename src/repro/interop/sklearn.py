@@ -179,8 +179,9 @@ def _gbt_init_pred(est, trees_by_class: list[list], lr: float,
                    n_features: int, K: int) -> np.ndarray:
     """The constant initial raw score, recovered through public API only:
     raw(x0) - lr * sum of tree outputs at x0, for a probe row x0."""
+    from sklearn.base import is_classifier
     x0 = np.zeros((1, n_features), np.float64)
-    if est._estimator_type == "classifier":
+    if is_classifier(est):
         raw = np.atleast_2d(est.decision_function(x0))  # (1,) -> (1, 1)
         if raw.shape == (1, 1) and K == 1:
             raw = raw.reshape(1, 1)

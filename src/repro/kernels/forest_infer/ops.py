@@ -2,14 +2,14 @@
 
 The serving contract (DESIGN.md §5.1) is that a compiled forest is uploaded
 to the device ONCE: ``forest_predict`` keeps a small id-keyed cache mapping a
-live Forest to (a) its raw SoA device arrays (ref kernel) and (b) its
-depth-packed device layout (tiled kernel, §5.2–§5.3), so repeat predictions
-do zero host-to-device transfers and zero re-packing. Entries are validated
-against a weakref (id reuse after GC cannot alias) and evicted LRU.
+live Forest to its depth-packed device layout (tiled kernel, §5.2–§5.3) and
+its depth-bucketed runners (§10), so repeat predictions do zero
+host-to-device transfers and zero re-packing. Entries are validated against
+a weakref (id reuse after GC cannot alias) and evicted LRU.
 
-impls: "pallas" (tiled, compiled), "interpret" (tiled, interpret mode —
-the CPU correctness path), "ref" (jnp gather oracle), "pallas_single"
-(legacy one-tree-per-step kernel; node capacity must fit its VMEM budget).
+impls: "pallas" (tiled, compiled — needs a TPU) and "interpret" (the same
+kernel in interpret mode — the CPU correctness path). With no impl given,
+a TPU host runs "pallas" and any other host "interpret".
 """
 from __future__ import annotations
 
@@ -21,10 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.forest_infer.forest_infer import (
-    forest_predict_pallas,
     forest_predict_pallas_tiled,
+    node_tables,
 )
-from repro.kernels.forest_infer.ref import forest_predict_ref
 
 _CACHE: "OrderedDict[int, tuple]" = OrderedDict()
 _CACHE_CAP = 8
@@ -52,29 +51,19 @@ def _forest_cache(forest) -> dict:
     return payload
 
 
-def device_soa(forest) -> tuple:
-    """Raw Forest SoA as device arrays, uploaded once per forest."""
-    c = _forest_cache(forest)
-    if "soa" not in c:
-        c["soa"] = (jnp.asarray(forest.feature), jnp.asarray(forest.threshold),
-                    jnp.asarray(forest.cat_mask),
-                    jnp.asarray(forest.left_child),
-                    jnp.asarray(forest.leaf_value))
-    return c["soa"]
-
-
 def device_packed(forest) -> tuple:
-    """Depth-packed device layout (pack_by_depth output), built/uploaded once.
-    Returns (feature, threshold, cat_mask, left_child, leaf_value,
-    block_depth, inv_order) with the first six on device."""
+    """Depth-packed device layout (pack_by_depth output) as the tiled
+    kernel's node tables, built/uploaded once. Returns (node_table,
+    leaf_table, block_depth, inv_order, out_dim), the first four on
+    device."""
     c = _forest_cache(forest)
     if "packed" not in c:
         from repro.core.tree import pack_by_depth
         p = pack_by_depth(forest)
-        c["packed"] = (jnp.asarray(p.feature), jnp.asarray(p.threshold),
-                       jnp.asarray(p.cat_mask), jnp.asarray(p.left_child),
-                       jnp.asarray(p.leaf_value), jnp.asarray(p.block_depth),
-                       jnp.asarray(p.inv_order))
+        tbl, leaf = node_tables(p.feature, p.threshold, p.cat_mask,
+                                p.left_child, p.leaf_value)
+        c["packed"] = (tbl, leaf, jnp.asarray(p.block_depth),
+                       jnp.asarray(p.inv_order), p.out_dim)
     return c["packed"]
 
 
@@ -125,20 +114,14 @@ def forest_predict(forest, X: np.ndarray, impl: str | None = None):
     """
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
-    if impl not in ("ref", "pallas_single", "pallas", "interpret"):
+    if impl not in ("pallas", "interpret"):
         raise ValueError(f"unknown impl {impl!r}")
     from repro.core.api import EngineFailure
     try:
         Xd = jnp.asarray(X, jnp.float32)
-        depth = int(max(1, forest.depth))
-        if impl == "ref":
-            return forest_predict_ref(Xd, *device_soa(forest), depth=depth)
-        if impl == "pallas_single":
-            return forest_predict_pallas(Xd, *device_soa(forest), depth=depth)
-        feat, thr, cat, lc, leaf, bd, inv = device_packed(forest)
-        out = forest_predict_pallas_tiled(
-            Xd, feat, thr, cat, lc, leaf, bd,
-            interpret=(impl == "interpret"))
+        tbl, leaf, bd, inv, out_dim = device_packed(forest)
+        out = forest_predict_pallas_tiled(Xd, tbl, leaf, bd, out_dim=out_dim,
+                                          interpret=(impl == "interpret"))
         return jnp.take(out, inv, axis=1)
     except (EngineFailure, KeyboardInterrupt):
         raise

@@ -20,10 +20,17 @@ from repro.kernels.histogram.histogram import histogram_pallas
 from repro.kernels.histogram.ref import histogram_ref
 
 
+def resolve_impl(impl: str | None) -> str:
+    """The implementation an ``impl`` request runs: "auto"/None is the
+    compiled kernel on a TPU and the jnp oracle elsewhere."""
+    if impl is None or impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return impl
+
+
 def histogram(codes, stats, node_of, n_nodes: int, n_bins: int = 256,
               impl: str | None = None):
-    if impl is None or impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "ref":
         return histogram_ref(codes, stats, node_of, n_nodes, n_bins)
     if impl == "pallas":
@@ -44,8 +51,7 @@ def fused_best_split(codes, stats, slot_of, n_slots: int, n_bins: int = 256,
     from repro.kernels.histogram.fused import fused_split_pallas
     from repro.kernels.histogram.ref import fused_split_ref
 
-    if impl is None or impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl(impl)
     if impl == "ref":
         return fused_split_ref(codes, stats, slot_of, n_slots, n_bins,
                                kind=kind, l2=l2, min_examples=min_examples)

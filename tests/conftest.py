@@ -14,7 +14,7 @@ def rng():
 
 
 def _make_random_forest(n_trees, n_splits_list, n_features, out_dim=1,
-                        seed=0, cat_feats=(), chain=False):
+                        seed=0, cat_feats=(), chain=False, max_depth=None):
     """Synthetic valid Forest (random leaf-splitting order): n_splits_list
     cycles per tree, so mixed entries build ragged-depth forests; entries
     over 2048 build >4096-node trees. cat_feats get random category masks.
@@ -33,8 +33,10 @@ def _make_random_forest(n_trees, n_splits_list, n_features, out_dim=1,
         leaves = [(0, 0)]
         n_nodes = 1
         for _ in range(n_splits_list[t % len(n_splits_list)]):
-            pick = (max(range(len(leaves)), key=lambda i: leaves[i][1])
-                    if chain else int(rng.integers(len(leaves))))
+            open_ = [i for i in range(len(leaves))
+                     if max_depth is None or leaves[i][1] < max_depth]
+            pick = (max(open_, key=lambda i: leaves[i][1])
+                    if chain else open_[int(rng.integers(len(open_)))])
             node, d = leaves.pop(pick)
             j = int(rng.integers(n_features))
             f.feature[t, node] = j

@@ -8,7 +8,8 @@ Contracts under test:
     forests against the sequential oracle engine for any block size (keyed
     feature sampling makes the growth schedule semantics-free);
   * the fused kernel's f32-accumulated gain argmax agrees with the f64 numpy
-    scan (property-style sweep over random frontiers);
+    scan (property-style sweep over random frontiers), and its class score's
+    log is within an ulp on any backend;
   * exact_subtraction gating: backends that do not accumulate in f64 are
     never served the parent-minus-sibling subtraction;
   * hist_backend hardening: "auto" pins to numpy on CPU hosts, forcing
@@ -218,6 +219,29 @@ def test_fused_f32_argmax_matches_f64_scan(kind):
                 assert (feat[i], sbin[i]) == (s.feature, s.split_bin), \
                     (f"seed {seed} impl {impl} slot {i}: f32 argmax "
                      "diverged from f64 scan")
+
+
+def test_class_score_log_is_within_an_ulp():
+    """The class layout's entropy uses ``log_f32``, not the backend's log
+    (a TPU's float32 log misses by up to ~1e-4): it must stay within one ulp
+    of the float64 log over the probabilities a node can hold, and a pure
+    node must score exactly 0."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.histogram.fused import log_f32, score_columns
+
+    rng = np.random.default_rng(0)
+    x = np.concatenate([rng.random(100_000), 1 - rng.random(10_000) * 1e-3,
+                        np.exp(rng.uniform(-27.6, 0, 10_000)),
+                        [1.0, 0.5, 1e-12]]).astype(np.float32)
+    x = x[x > 0]
+    got = np.asarray(jax.jit(log_f32)(x), np.float64)
+    want = np.log(x.astype(np.float64))
+    ulp = np.spacing(np.abs(want).astype(np.float32)).astype(np.float64)
+    assert (np.abs(got - want) <= np.maximum(ulp, 1e-45)).all()
+    pure = [jnp.float32(7.0), jnp.float32(0.0), jnp.float32(7.0)]
+    assert float(score_columns(pure, "class", 0.0)) == 0.0
 
 
 # ============================================ exact_subtraction gating
