@@ -106,6 +106,7 @@ def _drive(model, requests, clean_ref, qps: float, duration_s: float,
         # §13.4 survivorship fix: headline p50/p99 covers COMPLETED
         # requests only; shed/timed-out sojourn times are separate series
         "latency_by_outcome": m["latency_by_outcome"],
+        "queue_wait": m["queue_wait"],
         "equiv_checked": equiv_checked,
         "equiv_ok": equiv_ok,
     }

@@ -23,56 +23,57 @@ class CartLearner(Learner):
     def train(self, dataset, valid=None, checkpoint=None) -> CartModel:
         hp: CartHparams = self.hparams
         rng = np.random.default_rng(self.seed)
-        td = prepare_train_data(self, dataset, max_bins=hp.max_bins)
-        N = td.ds.n_rows
-        if valid is None and N >= 20:
-            tr_idx, va_idx = extract_validation(N, hp.validation_ratio, self.seed)
-        else:
-            tr_idx, va_idx = np.arange(N), np.arange(0)
-        if self.task == Task.CLASSIFICATION:
-            C = td.n_classes
-            stat_kind, out_dim = "class", C
-            base = np.concatenate([np.eye(C)[td.y], np.ones((N, 1))], 1)
+        with trace.span("learner/prepare", learner="cart"):
+            td = prepare_train_data(self, dataset, max_bins=hp.max_bins)
+            N = td.ds.n_rows
+            if valid is None and N >= 20:
+                tr_idx, va_idx = extract_validation(N, hp.validation_ratio, self.seed)
+            else:
+                tr_idx, va_idx = np.arange(N), np.arange(0)
+            if self.task == Task.CLASSIFICATION:
+                C = td.n_classes
+                stat_kind, out_dim = "class", C
+                base = np.concatenate([np.eye(C)[td.y], np.ones((N, 1))], 1)
 
-            def leaf_fn(s):
-                return (s[:-1] / max(s[-1], 1e-12)).astype(np.float32)
-        else:
-            stat_kind, out_dim = "moment", 1
-            base = np.stack([td.y, np.square(td.y), np.ones(N)], 1)
+                def leaf_fn(s):
+                    return (s[:-1] / max(s[-1], 1e-12)).astype(np.float32)
+            else:
+                stat_kind, out_dim = "moment", 1
+                base = np.stack([td.y, np.square(td.y), np.ones(N)], 1)
 
-            def leaf_fn(s):
-                return np.array([s[0] / max(s[-1], 1e-12)], np.float32)
+                def leaf_fn(s):
+                    return np.array([s[0] / max(s[-1], 1e-12)], np.float32)
 
-        sp = SplitterParams(stat_kind=stat_kind, min_examples=hp.min_examples,
-                            categorical_algorithm=hp.categorical_algorithm)
-        gp = GrowthParams(max_depth=hp.max_depth, max_nodes=hp.max_num_nodes,
-                          growing_strategy="LOCAL", splitter=sp,
-                          engine=hp.growth_engine,
-                          histogram_backend=hp.histogram_backend)
-        forest = empty_forest(1, hp.max_num_nodes, out_dim,
-                              feature_names=td.features)
-        forest.out_dim = out_dim
-        forest.tree_class = None
+            sp = SplitterParams(stat_kind=stat_kind, min_examples=hp.min_examples,
+                                categorical_algorithm=hp.categorical_algorithm)
+            gp = GrowthParams(max_depth=hp.max_depth, max_nodes=hp.max_num_nodes,
+                              growing_strategy="LOCAL", splitter=sp,
+                              engine=hp.growth_engine,
+                              histogram_backend=hp.histogram_backend)
+            forest = empty_forest(1, hp.max_num_nodes, out_dim,
+                                  feature_names=td.features)
+            forest.out_dim = out_dim
+            forest.tree_class = None
 
-        # -- checkpoint seam (DESIGN.md §11). A single tree has one interior
-        # boundary: grown-but-unpruned. Pruning is deterministic given
-        # (forest, seed-derived validation split), so resuming from the
-        # "grown" stage and re-pruning is bit-identical to a clean run.
-        from repro.train.checkpoint import (
-            forest_payload, open_session, restore_forest)
-        from repro.core.rf import training_data_fingerprint
-        sess = open_session(checkpoint, self.train_config(),
-                            training_data_fingerprint(td.X_raw, td.y))
-        state = sess.resume() if sess is not None else None
-        grown = pruned = False
-        interrupted = False
-        if state is not None:
-            restore_forest(forest, state["forest"])
-            grown, pruned = True, bool(state["done"])
+            # -- checkpoint seam (DESIGN.md §11). A single tree has one interior
+            # boundary: grown-but-unpruned. Pruning is deterministic given
+            # (forest, seed-derived validation split), so resuming from the
+            # "grown" stage and re-pruning is bit-identical to a clean run.
+            from repro.train.checkpoint import (
+                forest_payload, open_session, restore_forest)
+            from repro.core.rf import training_data_fingerprint
+            sess = open_session(checkpoint, self.train_config(),
+                                training_data_fingerprint(td.X_raw, td.y))
+            state = sess.resume() if sess is not None else None
+            grown = pruned = False
+            interrupted = False
+            if state is not None:
+                restore_forest(forest, state["forest"])
+                grown, pruned = True, bool(state["done"])
 
-        def _payload(complete: bool) -> dict:
-            return {"kind": "cart", "trees_done": 1, "done": bool(complete),
-                    "forest": forest_payload(forest, 1)}
+            def _payload(complete: bool) -> dict:
+                return {"kind": "cart", "trees_done": 1, "done": bool(complete),
+                        "forest": forest_payload(forest, 1)}
 
         import contextlib
         with (sess if sess is not None else contextlib.nullcontext()):

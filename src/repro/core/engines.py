@@ -41,6 +41,7 @@ round-tripped predictor keeps its engine choice without shipping closures.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Callable
 
@@ -179,14 +180,34 @@ def _compile_forest_engine(forest: Forest, engine: str | None) -> Engine:
             raise YdfError(
                 f"Model is not compatible with the 'pallas' engine: {reason}. "
                 f"Compatible engines: {available_engines(forest)}.")
-        from repro.kernels.forest_infer.ops import device_packed, forest_predict
+        from repro.kernels.forest_infer.ops import device_packed
         device_packed(forest)  # upload the depth-packed layout once, now
-        return Engine("pallas", lambda X: np.asarray(forest_predict(forest, X)),
+        return Engine("pallas", functools.partial(_pallas_per_tree, forest),
                       note="tree-tiled over depth-packed blocks (§5.2); "
                            "interpret-mode on CPU, compiled on TPU",
                       forest=forest)
     raise YdfError(f"Unknown engine {engine!r}. "
                    f"Available: {available_engines(forest)}.")
+
+
+def _pallas_per_tree(forest: Forest, X: np.ndarray) -> np.ndarray:
+    """The pallas engine's call: upload the batch, run the kernel and the
+    reorder to the original tree order, copy the per-tree output back.
+    While tracing, the upload and the kernel block until the device is
+    done, so each span holds its own device time."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.forest_infer.ops import forest_predict
+    with trace.span("engines/upload", rows=len(X)):
+        Xd = jnp.asarray(X, jnp.float32)
+        if trace.enabled():
+            jax.block_until_ready(Xd)
+    with trace.span("engines/kernel", rows=len(X)):
+        out = forest_predict(forest, Xd)
+        if trace.enabled():
+            jax.block_until_ready(out)
+    with trace.span("engines/to_host", bytes=out.nbytes):
+        return np.asarray(out)
 
 
 # engines whose first call at a new batch shape traces/compiles — the layer
@@ -227,7 +248,8 @@ class CompiledPredictor:
         return self.engine.name
 
     def encode(self, dataset) -> np.ndarray:
-        return self.encoder.encode(dataset)
+        with trace.span("engines/encode"):
+            return self.encoder.encode(dataset)
 
     def per_tree(self, X: np.ndarray) -> np.ndarray:
         # engine failures surface TYPED (DESIGN.md §9.1): the serving
@@ -248,7 +270,9 @@ class CompiledPredictor:
     def predict_encoded(self, X: np.ndarray) -> np.ndarray:
         if len(X) == 0:
             return np.zeros((0,) + self.out_shape, np.float32)
-        return self.finalize(np.asarray(self.per_tree(X)))
+        per_tree = np.asarray(self.per_tree(X))
+        with trace.span("engines/finalize", rows=len(X)):
+            return self.finalize(per_tree)
 
     def predict(self, dataset) -> np.ndarray:
         return self.predict_encoded(self.encode(dataset))

@@ -155,42 +155,53 @@ def _level_step(cfg: _StepConfig):
         act = loc >= 0
         locc = jnp.maximum(loc, 0)
         # per-example candidate codes: codes[i, fsel_c[k, loc[k,i], j]]
-        fex = jnp.take_along_axis(
-            fsel_c, locc[:, :, None], axis=1)                 # (K, N, kf)
-        cex = codes[jnp.arange(N)[None, :, None], fex]        # (K, N, kf)
+        with jax.named_scope("candidate_gather"):
+            fex = jnp.take_along_axis(
+                fsel_c, locc[:, :, None], axis=1)                 # (K, N, kf)
+            cex = codes[jnp.arange(N)[None, :, None], fex]        # (K, N, kf)
 
         if cfg.impl in ("pallas", "interpret"):
             # fused kernel: hist + numerical scan + argmax fully in VMEM
-            gains, js, sbins = [], [], []
-            for k in range(K):
-                gk, jk, bk = fused_split_pallas(
-                    cex[k], stats[k], loc[k], w_slots,
-                    _B, kind=kind, l2=l2, min_examples=min_ex,
-                    interpret=(cfg.impl == "interpret"))
-                gains.append(gk), js.append(jk), sbins.append(bk)
-            gain = jnp.stack(gains)                           # (K, W)
-            jwin = jnp.maximum(jnp.stack(js), 0)
-            sbin = jnp.stack(sbins)
-            feat = jnp.take_along_axis(
-                fsel_c, jwin[:, :, None], axis=2)[:, :, 0]
-            tbl = (jnp.arange(_B)[None, None, :] >= sbin[:, :, None])
-            iscat_w = jnp.zeros(gain.shape, bool)
-            seg = jnp.where(act, loc, w_slots)
-            pstats = jax.vmap(lambda s, v: jax.ops.segment_sum(
-                v, s, num_segments=w_slots + 1))(
-                    seg, jnp.where(act[:, :, None], stats, 0.0))
-            ps = score_stats(pstats[:, :w_slots], kind, l2)   # (K, W)
+            with jax.named_scope("histogram"):
+                gains, js, sbins = [], [], []
+                for k in range(K):
+                    gk, jk, bk = fused_split_pallas(
+                        cex[k], stats[k], loc[k], w_slots,
+                        _B, kind=kind, l2=l2, min_examples=min_ex,
+                        interpret=(cfg.impl == "interpret"))
+                    gains.append(gk), js.append(jk), sbins.append(bk)
+            with jax.named_scope("gain_scan"):
+                gain = jnp.stack(gains)                           # (K, W)
+                jwin = jnp.maximum(jnp.stack(js), 0)
+                sbin = jnp.stack(sbins)
+                feat = jnp.take_along_axis(
+                    fsel_c, jwin[:, :, None], axis=2)[:, :, 0]
+                tbl = (jnp.arange(_B)[None, None, :] >= sbin[:, :, None])
+                iscat_w = jnp.zeros(gain.shape, bool)
+                seg = jnp.where(act, loc, w_slots)
+                pstats = jax.vmap(lambda s, v: jax.ops.segment_sum(
+                    v, s, num_segments=w_slots + 1))(
+                        seg, jnp.where(act[:, :, None], stats, 0.0))
+                ps = score_stats(pstats[:, :w_slots], kind, l2)   # (K, W)
             return gain, feat, sbin, iscat_w, tbl, ps
 
         # ---- jnp path: explicit histogram + both scans under the same jit
-        ws = jnp.where(act[:, :, None], stats, 0.0)           # (K, N, S)
-        hists = []
-        for j in range(kf):
-            seg = jnp.where(act, locc * _B + cex[:, :, j], w_slots * _B)
-            h = jax.vmap(lambda s, v: jax.ops.segment_sum(
-                v, s, num_segments=w_slots * _B + 1))(seg, ws)
-            hists.append(h[:, :w_slots * _B].reshape(K, w_slots, _B, S))
-        hist = jnp.stack(hists, axis=2)                       # (K, W, kf, B, S)
+        with jax.named_scope("histogram"):
+            ws = jnp.where(act[:, :, None], stats, 0.0)           # (K, N, S)
+            hists = []
+            for j in range(kf):
+                seg = jnp.where(act, locc * _B + cex[:, :, j], w_slots * _B)
+                h = jax.vmap(lambda s, v: jax.ops.segment_sum(
+                    v, s, num_segments=w_slots * _B + 1))(seg, ws)
+                hists.append(h[:, :w_slots * _B].reshape(K, w_slots, _B, S))
+            hist = jnp.stack(hists, axis=2)                  # (K, W, kf, B, S)
+        with jax.named_scope("gain_scan"):
+            return scan_best(hist, nbins, iscat, fsel_c)
+
+    def scan_best(hist, nbins, iscat, fsel_c):
+        """Both gain scans over one chunk's (K, W, kf, B, S) histograms:
+        the best split per slot and its go-right-by-code table."""
+        K, w_slots = hist.shape[:2]
         parent = hist.sum(axis=3)                             # (K, W, kf, S)
 
         g_num = _numerical_gains(hist, parent, kind, l2, min_ex)
@@ -265,13 +276,14 @@ def _level_step(cfg: _StepConfig):
         karange = jnp.arange(K)[:, None]
 
         # 1. candidate features per (tree, slot), keyed by (tree, node id)
-        if cfg.sample:
-            fsel = keyed_feature_select_jnp(
-                cfg.sampling_key, tree_ids[:, None],
-                jnp.maximum(slot_node, 0), cfg.F, kf)         # (K, P, kf)
-        else:
-            fsel = jnp.broadcast_to(jnp.arange(cfg.F, dtype=jnp.int32),
-                                    (K, P, cfg.F))
+        with jax.named_scope("candidate_gather"):
+            if cfg.sample:
+                fsel = keyed_feature_select_jnp(
+                    cfg.sampling_key, tree_ids[:, None],
+                    jnp.maximum(slot_node, 0), cfg.F, kf)         # (K, P, kf)
+            else:
+                fsel = jnp.broadcast_to(jnp.arange(cfg.F, dtype=jnp.int32),
+                                        (K, P, cfg.F))
 
         # 2. best split per slot, W slots at a time (bounds hist scratch)
         W = min(P, _W_CAP)
@@ -288,53 +300,56 @@ def _level_step(cfg: _StepConfig):
         # 3. validity + child allocation (frontier-order, budget-capped).
         # The gain floor is scale-aware (splitters.REL_GAIN_EPS): f32 noise
         # around a true gain of 0 must not read as a valid split.
-        floor = jnp.maximum(cfg.min_gain, _REL_EPS * jnp.abs(ps))
-        valid = (gain > floor) & jnp.isfinite(gain) & (slot_node >= 0)
-        vi = valid.astype(jnp.int32)
-        rank = jnp.cumsum(vi, axis=1) - vi                    # exclusive
-        valid &= nn[:, None] + 2 * (rank + 1) <= cfg.max_nodes
-        left_id = jnp.where(valid, nn[:, None] + 2 * rank, -1)
-        nv = valid.sum(axis=1).astype(jnp.int32)
-        nn = nn + 2 * nv
-        depth = depth + (nv > 0)
+        with jax.named_scope("forest_write"):
+            floor = jnp.maximum(cfg.min_gain, _REL_EPS * jnp.abs(ps))
+            valid = (gain > floor) & jnp.isfinite(gain) & (slot_node >= 0)
+            vi = valid.astype(jnp.int32)
+            rank = jnp.cumsum(vi, axis=1) - vi                    # exclusive
+            valid &= nn[:, None] + 2 * (rank + 1) <= cfg.max_nodes
+            left_id = jnp.where(valid, nn[:, None] + 2 * rank, -1)
+            nv = valid.sum(axis=1).astype(jnp.int32)
+            nn = nn + 2 * nv
+            depth = depth + (nv > 0)
 
-        # 4. write the chosen conditions into the device forest arrays
-        pidx = jnp.where(valid, slot_node, M)                 # M drops
-        feat_a = feat_a.at[karange, pidx].set(feat_w, mode="drop")
-        sbin_a = sbin_a.at[karange, pidx].set(sbin_w, mode="drop")
-        left_a = left_a.at[karange, pidx].set(left_id, mode="drop")
-        gain_a = gain_a.at[karange, pidx].set(jnp.maximum(gain, 0.0),
-                                              mode="drop")
-        bits = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-        packed = (tbl.reshape(K, P, MASK_WORDS, 32).astype(jnp.uint32)
-                  * bits).sum(axis=3, dtype=jnp.uint32)
-        cidx = jnp.where(valid & iscat_w, slot_node, M)
-        catm_a = catm_a.at[karange, cidx].set(packed, mode="drop")
+            # 4. write the chosen conditions into the device forest arrays
+            pidx = jnp.where(valid, slot_node, M)                 # M drops
+            feat_a = feat_a.at[karange, pidx].set(feat_w, mode="drop")
+            sbin_a = sbin_a.at[karange, pidx].set(sbin_w, mode="drop")
+            left_a = left_a.at[karange, pidx].set(left_id, mode="drop")
+            gain_a = gain_a.at[karange, pidx].set(jnp.maximum(gain, 0.0),
+                                                  mode="drop")
+            bits = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
+            packed = (tbl.reshape(K, P, MASK_WORDS, 32).astype(jnp.uint32)
+                      * bits).sum(axis=3, dtype=jnp.uint32)
+            cidx = jnp.where(valid & iscat_w, slot_node, M)
+            catm_a = catm_a.at[karange, cidx].set(packed, mode="drop")
 
         # 5. route every example of a split slot to its child
-        slotc = jnp.maximum(slot_of, 0)
-        route = (slot_of >= 0) & jnp.take_along_axis(valid, slotc, axis=1)
-        f_ex = jnp.take_along_axis(feat_w, slotc, axis=1)     # (K, N)
-        c_ex = codes[jnp.arange(N)[None, :], f_ex]
-        go = tbl[karange, slotc, c_ex]
-        l_ex = jnp.take_along_axis(left_id, slotc, axis=1)
-        node_of = jnp.where(route, l_ex + go, node_of)
-        r_ex = jnp.take_along_axis(rank, slotc, axis=1)
-        slot_of = jnp.where(route, 2 * r_ex + go, -1)
+        with jax.named_scope("routing"):
+            slotc = jnp.maximum(slot_of, 0)
+            route = (slot_of >= 0) & jnp.take_along_axis(valid, slotc, axis=1)
+            f_ex = jnp.take_along_axis(feat_w, slotc, axis=1)     # (K, N)
+            c_ex = codes[jnp.arange(N)[None, :], f_ex]
+            go = tbl[karange, slotc, c_ex]
+            l_ex = jnp.take_along_axis(left_id, slotc, axis=1)
+            node_of = jnp.where(route, l_ex + go, node_of)
+            r_ex = jnp.take_along_axis(rank, slotc, axis=1)
+            slot_of = jnp.where(route, 2 * r_ex + go, -1)
 
         # 6. child stats in one segment-sum; new frontier = compacted children
-        seg = jnp.where(slot_of >= 0, slot_of, 2 * P)
-        csum = jax.vmap(lambda s, v: jax.ops.segment_sum(
-            v, s, num_segments=2 * P + 1))(
-                seg, jnp.where(slot_of[:, :, None] >= 0, stats, 0.0))
-        csum = csum[:, :2 * P]                                # (K, 2P, S)
-        child_node = jnp.full((K, 2 * P), -1, jnp.int32)
-        lidx = jnp.where(valid, 2 * rank, 2 * P)
-        child_node = child_node.at[karange, lidx].set(left_id, mode="drop")
-        child_node = child_node.at[karange, lidx + 1].set(left_id + 1,
-                                                          mode="drop")
-        nidx = jnp.where(child_node >= 0, child_node, M)
-        lstats_a = lstats_a.at[karange, nidx].set(csum, mode="drop")
+        with jax.named_scope("child_stats"):
+            seg = jnp.where(slot_of >= 0, slot_of, 2 * P)
+            csum = jax.vmap(lambda s, v: jax.ops.segment_sum(
+                v, s, num_segments=2 * P + 1))(
+                    seg, jnp.where(slot_of[:, :, None] >= 0, stats, 0.0))
+            csum = csum[:, :2 * P]                                # (K, 2P, S)
+            child_node = jnp.full((K, 2 * P), -1, jnp.int32)
+            lidx = jnp.where(valid, 2 * rank, 2 * P)
+            child_node = child_node.at[karange, lidx].set(left_id, mode="drop")
+            child_node = child_node.at[karange, lidx + 1].set(left_id + 1,
+                                                              mode="drop")
+            nidx = jnp.where(child_node >= 0, child_node, M)
+            lstats_a = lstats_a.at[karange, nidx].set(csum, mode="drop")
 
         return (slot_of, child_node, feat_a, sbin_a, catm_a, left_a, gain_a,
                 lstats_a, nn, node_of, depth, nv)
@@ -352,9 +367,10 @@ def _device_codes(binned: BinnedFeatures):
     import jax.numpy as jnp
     cached = getattr(binned, "_device_codes", None)
     if cached is None:
-        cached = (jnp.asarray(binned.codes.astype(np.int32)),
-                  jnp.asarray(binned.n_bins.astype(np.int32)),
-                  jnp.asarray(binned.is_cat))
+        with trace.span("grower_device/codes", rows=binned.codes.shape[0]):
+            cached = (jnp.asarray(binned.codes.astype(np.int32)),
+                      jnp.asarray(binned.n_bins.astype(np.int32)),
+                      jnp.asarray(binned.is_cat))
         binned._device_codes = cached
     return cached
 
@@ -391,13 +407,14 @@ def grow_trees_device(forest: Forest, ts, binned: BinnedFeatures,
     step = _level_step(cfg)
 
     codes, nbins, iscat = _device_codes(binned)
-    stats_np = np.zeros((K, N, S), np.float32)
-    act_np = np.zeros((K, N), bool)
-    for b in range(Kr):
-        stats_np[b] = stats_list[b].astype(np.float32)
-        act_np[b] = actives[b]
-    stats = jnp.asarray(stats_np)
-    node_of = jnp.asarray(np.where(act_np, 0, -1).astype(np.int32))
+    with trace.span("grower_device/upload", trees=Kr, rows=N):
+        stats_np = np.zeros((K, N, S), np.float32)
+        act_np = np.zeros((K, N), bool)
+        for b in range(Kr):
+            stats_np[b] = stats_list[b].astype(np.float32)
+            act_np[b] = actives[b]
+        stats = jnp.asarray(stats_np)
+        node_of = jnp.asarray(np.where(act_np, 0, -1).astype(np.int32))
     slot_of = node_of
     slot_node = jnp.zeros((K, 1), jnp.int32)
     tree_ids = jnp.asarray(np.asarray(
@@ -438,34 +455,38 @@ def grow_trees_device(forest: Forest, ts, binned: BinnedFeatures,
          lstats_a, nn, node_of, depth, nv) = out
         # the single per-level host sync: the compacted frontier width,
         # used to choose the next power-of-two shape bucket
-        with trace.span("grower_device/host_sync", level=_level):
-            nv_max = int(nv.max())
+        nv_max = int(nv.max())
         if nv_max == 0:
             break
         P_next = _next_pow2(2 * nv_max)
         slot_node = slot_node[:, :P_next]
 
-    # one fetch per block: decode device arrays into the host Forest
+    # one fetch per block: the device arrays copied to the host
     with trace.span("grower_device/fetch", trees=Kr):
         (feat_h, sbin_h, catm_h, left_h, gain_h, lstats_h, nn_h, node_h,
          depth_h) = tuple(np.asarray(a) for a in
                           (feat_a, sbin_a, catm_a, left_a, gain_a, lstats_a,
                            nn, node_of, depth))
-    for b, t in enumerate(ts):
-        n_t = int(nn_h[b])
-        forest.n_nodes[t] = n_t
-        forest.feature[t, :M] = feat_h[b]
-        forest.left_child[t, :M] = left_h[b]
-        forest.cat_mask[t, :M] = catm_h[b]
-        forest.split_bin[t, :M] = np.maximum(sbin_h[b], 0).astype(np.uint16)
-        if forest.split_gain is not None:
-            forest.split_gain[t, :M] = gain_h[b]
-        for n in range(1, n_t):
-            forest.leaf_value[t, n] = leaf_fn(lstats_h[b, n].astype(np.float64))
-        for n in np.where((feat_h[b, :n_t] >= 0)
-                          & ~binned.is_cat[np.maximum(feat_h[b, :n_t], 0)])[0]:
-            f, sb = int(feat_h[b, n]), int(sbin_h[b, n])
-            sb = min(sb, len(binned.boundaries[f]))
-            forest.threshold[t, n] = binned.threshold_value(f, sb)
-        forest.depth = max(forest.depth, int(depth_h[b]))
+    # decode: leaves and thresholds written into the host Forest
+    with trace.span("grower_device/decode", trees=Kr):
+        for b, t in enumerate(ts):
+            n_t = int(nn_h[b])
+            forest.n_nodes[t] = n_t
+            forest.feature[t, :M] = feat_h[b]
+            forest.left_child[t, :M] = left_h[b]
+            forest.cat_mask[t, :M] = catm_h[b]
+            forest.split_bin[t, :M] = np.maximum(sbin_h[b],
+                                                 0).astype(np.uint16)
+            if forest.split_gain is not None:
+                forest.split_gain[t, :M] = gain_h[b]
+            for n in range(1, n_t):
+                forest.leaf_value[t, n] = leaf_fn(
+                    lstats_h[b, n].astype(np.float64))
+            num = (feat_h[b, :n_t] >= 0) & ~binned.is_cat[
+                np.maximum(feat_h[b, :n_t], 0)]
+            for n in np.where(num)[0]:
+                f, sb = int(feat_h[b, n]), int(sbin_h[b, n])
+                sb = min(sb, len(binned.boundaries[f]))
+                forest.threshold[t, n] = binned.threshold_value(f, sb)
+            forest.depth = max(forest.depth, int(depth_h[b]))
     return node_h[:Kr]

@@ -44,87 +44,88 @@ class RandomForestLearner(Learner):
 
     def train(self, dataset, valid=None, checkpoint=None) -> RandomForestModel:
         hp: RFHparams = self.hparams
-        td = prepare_train_data(self, dataset, max_bins=hp.max_bins)
-        N, F = td.binned.codes.shape
-        if self.task == Task.CLASSIFICATION:
-            C = td.n_classes
-            stat_kind, out_dim, S = "class", C, C + 1
-            onehot = np.eye(C)[td.y]                     # (N, C)
-            base_stats = np.concatenate([onehot, np.ones((N, 1))], 1)
+        with trace.span("learner/prepare", learner="rf"):
+            td = prepare_train_data(self, dataset, max_bins=hp.max_bins)
+            N, F = td.binned.codes.shape
+            if self.task == Task.CLASSIFICATION:
+                C = td.n_classes
+                stat_kind, out_dim, S = "class", C, C + 1
+                onehot = np.eye(C)[td.y]                     # (N, C)
+                base_stats = np.concatenate([onehot, np.ones((N, 1))], 1)
 
-            def leaf_fn(s):
-                tot = max(s[-1], 1e-12)
-                return (s[:-1] / tot).astype(np.float32)
-        else:
-            stat_kind, out_dim, S = "moment", 1, 3
-            base_stats = np.stack([td.y, np.square(td.y), np.ones(N)], 1)
+                def leaf_fn(s):
+                    tot = max(s[-1], 1e-12)
+                    return (s[:-1] / tot).astype(np.float32)
+            else:
+                stat_kind, out_dim, S = "moment", 1, 3
+                base_stats = np.stack([td.y, np.square(td.y), np.ones(N)], 1)
 
-            def leaf_fn(s):
-                return np.array([s[0] / max(s[-1], 1e-12)], np.float32)
+                def leaf_fn(s):
+                    return np.array([s[0] / max(s[-1], 1e-12)], np.float32)
 
-        if hp.num_candidate_attributes == "SQRT":
-            ratio = min(1.0, np.sqrt(F) / F)  # Breiman rule of thumb
-        elif hp.num_candidate_attributes == "ALL":
-            ratio = 1.0
-        else:
-            ratio = float(hp.num_candidate_attributes)
-        oblique = hp.split_axis == "SPARSE_OBLIQUE"
-        sp = SplitterParams(
-            stat_kind=stat_kind, min_examples=hp.min_examples,
-            categorical_algorithm=hp.categorical_algorithm,
-            num_candidate_ratio=ratio, oblique=oblique,
-            oblique_num_projections_exponent=hp.sparse_oblique_num_projections_exponent)
-        # Per-tree rng streams + keyed per-node feature sampling: every draw
-        # is a function of (seed, tree) or (seed, tree, node), never of the
-        # order trees or nodes are processed in. That makes the growth
-        # schedule semantics-free, so independent trees can grow as lockstep
-        # BLOCKS (one level pass over tree_parallelism trees at a time —
-        # grower.grow_trees / DESIGN.md §6.3) with forests bit-identical to
-        # sequential growth at equal seeds (tested).
-        gp = GrowthParams(max_depth=hp.max_depth, max_nodes=hp.max_num_nodes,
-                          growing_strategy=hp.growing_strategy, splitter=sp,
-                          engine=hp.growth_engine,
-                          histogram_backend=hp.histogram_backend,
-                          feature_sampling="keyed",
-                          sampling_key=self.seed & 0xFFFFFFFF)
-        engine_used, fallback = resolve_engine(gp, td.binned, oblique)
-        block = max(1, int(hp.tree_parallelism))
-        n_num = int((~td.binned.is_cat).sum())
-        forest = empty_forest(hp.num_trees, hp.max_num_nodes, out_dim,
-                              oblique_dims=n_num if oblique else 0,
-                              feature_names=td.features)
-        forest.out_dim = out_dim
-        forest.tree_class = None
-        forest.init_pred = np.zeros(out_dim, np.float32)
+            if hp.num_candidate_attributes == "SQRT":
+                ratio = min(1.0, np.sqrt(F) / F)  # Breiman rule of thumb
+            elif hp.num_candidate_attributes == "ALL":
+                ratio = 1.0
+            else:
+                ratio = float(hp.num_candidate_attributes)
+            oblique = hp.split_axis == "SPARSE_OBLIQUE"
+            sp = SplitterParams(
+                stat_kind=stat_kind, min_examples=hp.min_examples,
+                categorical_algorithm=hp.categorical_algorithm,
+                num_candidate_ratio=ratio, oblique=oblique,
+                oblique_num_projections_exponent=hp.sparse_oblique_num_projections_exponent)
+            # Per-tree rng streams + keyed per-node feature sampling: every draw
+            # is a function of (seed, tree) or (seed, tree, node), never of the
+            # order trees or nodes are processed in. That makes the growth
+            # schedule semantics-free, so independent trees can grow as lockstep
+            # BLOCKS (one level pass over tree_parallelism trees at a time —
+            # grower.grow_trees / DESIGN.md §6.3) with forests bit-identical to
+            # sequential growth at equal seeds (tested).
+            gp = GrowthParams(max_depth=hp.max_depth, max_nodes=hp.max_num_nodes,
+                              growing_strategy=hp.growing_strategy, splitter=sp,
+                              engine=hp.growth_engine,
+                              histogram_backend=hp.histogram_backend,
+                              feature_sampling="keyed",
+                              sampling_key=self.seed & 0xFFFFFFFF)
+            engine_used, fallback = resolve_engine(gp, td.binned, oblique)
+            block = max(1, int(hp.tree_parallelism))
+            n_num = int((~td.binned.is_cat).sum())
+            forest = empty_forest(hp.num_trees, hp.max_num_nodes, out_dim,
+                                  oblique_dims=n_num if oblique else 0,
+                                  feature_names=td.features)
+            forest.out_dim = out_dim
+            forest.tree_class = None
+            forest.init_pred = np.zeros(out_dim, np.float32)
 
-        oob_sum = np.zeros((N, out_dim), np.float64)
-        oob_cnt = np.zeros(N, np.int64)
-        tree_rng = [np.random.default_rng((self.seed & 0xFFFFFFFF, 104729, t))
-                    for t in range(hp.num_trees)]
+            oob_sum = np.zeros((N, out_dim), np.float64)
+            oob_cnt = np.zeros(N, np.int64)
+            tree_rng = [np.random.default_rng((self.seed & 0xFFFFFFFF, 104729, t))
+                        for t in range(hp.num_trees)]
 
-        # -- checkpoint seam (DESIGN.md §11). RF checkpoints only at
-        # LOCKSTEP BLOCK boundaries so the resumed `range(trees_done, ...)`
-        # realigns with the tree-parallel blocks; per-tree keyed rng streams
-        # are re-derived from (seed, tree), so no generator state is stored.
-        from repro.train.checkpoint import (
-            forest_payload, open_session, restore_forest)
-        sess = open_session(checkpoint, self.train_config(),
-                            training_data_fingerprint(td.X_raw, td.y))
-        trees_done, interrupted = 0, False
+            # -- checkpoint seam (DESIGN.md §11). RF checkpoints only at
+            # LOCKSTEP BLOCK boundaries so the resumed `range(trees_done, ...)`
+            # realigns with the tree-parallel blocks; per-tree keyed rng streams
+            # are re-derived from (seed, tree), so no generator state is stored.
+            from repro.train.checkpoint import (
+                forest_payload, open_session, restore_forest)
+            sess = open_session(checkpoint, self.train_config(),
+                                training_data_fingerprint(td.X_raw, td.y))
+            trees_done, interrupted = 0, False
 
-        def _payload(complete: bool) -> dict:
-            return {"kind": "rf", "trees_done": trees_done,
-                    "done": bool(complete),
-                    "forest": forest_payload(forest, trees_done),
-                    "oob_sum": np.copy(oob_sum), "oob_cnt": np.copy(oob_cnt)}
+            def _payload(complete: bool) -> dict:
+                return {"kind": "rf", "trees_done": trees_done,
+                        "done": bool(complete),
+                        "forest": forest_payload(forest, trees_done),
+                        "oob_sum": np.copy(oob_sum), "oob_cnt": np.copy(oob_cnt)}
 
-        if sess is not None:
-            state = sess.resume()
-            if state is not None:
-                trees_done = int(state["trees_done"])
-                restore_forest(forest, state["forest"])
-                oob_sum[:] = state["oob_sum"]
-                oob_cnt[:] = state["oob_cnt"]
+            if sess is not None:
+                state = sess.resume()
+                if state is not None:
+                    trees_done = int(state["trees_done"])
+                    restore_forest(forest, state["forest"])
+                    oob_sum[:] = state["oob_sum"]
+                    oob_cnt[:] = state["oob_cnt"]
 
         import contextlib
         with (sess if sess is not None else contextlib.nullcontext()):

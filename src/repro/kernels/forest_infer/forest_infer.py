@@ -146,8 +146,9 @@ def forest_predict_pallas_tiled(X, tbl, leaf, block_depth, *, out_dim: int,
     TN = min(_round_up(tile_n, 128), _round_up(max(N, 1), 128))
     Np = _round_up(max(N, 1), TN)
     # examples on lanes; feature rows padded to whole sublane groups
-    Xt = jnp.pad(X.astype(jnp.float32).T,
-                 ((0, _round_up(F, 8) - F), (0, Np - N)))
+    with jax.named_scope("layout"):
+        Xt = jnp.pad(X.astype(jnp.float32).T,
+                     ((0, _round_up(F, 8) - F), (0, Np - N)))
     out = pl.pallas_call(
         functools.partial(_infer_tiled_kernel, node_tile=mt),
         grid=(Np // TN, B),
@@ -160,5 +161,7 @@ def forest_predict_pallas_tiled(X, tbl, leaf, block_depth, *, out_dim: int,
         out_specs=pl.BlockSpec((TB, Op, TN), lambda i, b: (b, 0, i)),
         out_shape=jax.ShapeDtypeStruct((B * TB, Op, Np), jnp.float32),
         interpret=interpret,
+        name="forest_predict_pallas_tiled",
     )(block_depth.reshape(B).astype(jnp.int32), Xt, tbl, leaf)
-    return out[:, :out_dim, :N].transpose(2, 0, 1)
+    with jax.named_scope("unpad"):
+        return out[:, :out_dim, :N].transpose(2, 0, 1)

@@ -206,5 +206,6 @@ def fused_split_pallas(codes: jax.Array, stats: jax.Array, slot_of: jax.Array,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((S, Wp, n_bins), jnp.float32)],
         interpret=interpret,
+        name="fused_split_pallas",
     )(codes_t, stats_t, slot_t)
     return gain[:n_slots, 0], feat[:n_slots, 0], sbin[:n_slots, 0]

@@ -105,5 +105,6 @@ def histogram_pallas(codes: jax.Array, stats: jax.Array, node_of: jax.Array,
                                lambda f, i: (f, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((F, S, Wn, n_bins), jnp.float32),
         interpret=interpret,
+        name="histogram_pallas",
     )(codes_t, stats_t, node_t)
     return out.transpose(2, 0, 3, 1)[:n_nodes]            # (nodes, F, B, S)

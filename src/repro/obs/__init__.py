@@ -1,7 +1,7 @@
 """Unified observability layer (DESIGN.md §13).
 
 - ``obs.trace``   — nested spans, injectable clock, zero-cost disabled path
-- ``obs.metrics`` — counters / gauges / bounded-reservoir histograms
+- ``obs.metrics`` — counters / bounded-reservoir histograms
 - ``obs.export``  — Chrome trace-event + phase-aggregate exporters
 - ``obs.logs``    — the standardized ``training_logs`` schema
 - ``obs.clock``   — the sanctioned timing sources for all of ``src/``
@@ -11,7 +11,7 @@ from .export import chrome_trace, phase_summary, profile_dict, \
     write_chrome_trace
 from .logs import build_training_logs, summarize_training_logs, \
     validate_training_logs
-from .metrics import Counter, Gauge, Histogram, MetricsRegistry
+from .metrics import Counter, Histogram, MetricsRegistry
 from .trace import Span, Tracer, capture, enabled, event, span
 
 __all__ = [
@@ -19,6 +19,6 @@ __all__ = [
     "chrome_trace", "phase_summary", "profile_dict", "write_chrome_trace",
     "build_training_logs", "summarize_training_logs",
     "validate_training_logs",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "Counter", "Histogram", "MetricsRegistry",
     "Span", "Tracer", "capture", "enabled", "event", "span",
 ]

@@ -1,4 +1,4 @@
-"""Metrics registry: counters, gauges, bounded-reservoir histograms.
+"""Metrics registry: counters and bounded-reservoir histograms.
 
 One schema for everything that counts or samples: serving counters
 (`ServerMetrics` is a facade over this registry since §13), training
@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
+__all__ = ["Counter", "Histogram", "MetricsRegistry",
            "DEFAULT_RESERVOIR_CAP"]
 
 DEFAULT_RESERVOIR_CAP = 65536
@@ -46,21 +46,6 @@ class Counter:
         self.value += n
 
     def to_value(self) -> int:
-        return self.value
-
-
-class Gauge:
-    """Last-write-wins float sample (queue depth, EWMA rate, ...)."""
-
-    __slots__ = ("value",)
-
-    def __init__(self, value: float = 0.0) -> None:
-        self.value = value
-
-    def set(self, v: float) -> None:
-        self.value = float(v)
-
-    def to_value(self) -> float:
         return self.value
 
 
@@ -120,11 +105,10 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Labeled series of counters, gauges and histograms.
+    """Labeled series of counters and histograms.
 
-    ``counter/gauge/histogram`` are get-or-create: instrumented code
-    never pre-registers. ``merge`` adds counters, sums histograms and
-    takes the other registry's gauges (last write wins), so worker
+    ``counter/histogram`` are get-or-create: instrumented code never
+    pre-registers. ``merge`` adds counters and pools histograms, so worker
     registries roll up into a coordinator's without key coordination.
     """
 
@@ -132,7 +116,6 @@ class MetricsRegistry:
 
     def __init__(self) -> None:
         self._counters: Dict[Tuple[str, _LabelKey], Counter] = {}
-        self._gauges: Dict[Tuple[str, _LabelKey], Gauge] = {}
         self._hists: Dict[Tuple[str, _LabelKey], Histogram] = {}
 
     # -- get-or-create accessors --------------------------------------
@@ -142,13 +125,6 @@ class MetricsRegistry:
         if c is None:
             c = self._counters[key] = Counter()
         return c
-
-    def gauge(self, name: str, **labels: Any) -> Gauge:
-        key = (name, _label_key(labels))
-        g = self._gauges.get(key)
-        if g is None:
-            g = self._gauges[key] = Gauge()
-        return g
 
     def histogram(self, name: str, cap: int = DEFAULT_RESERVOIR_CAP,
                   **labels: Any) -> Histogram:
@@ -161,8 +137,8 @@ class MetricsRegistry:
     # -- queries -------------------------------------------------------
     def series(self, name: str) -> Iterator[Tuple[Dict[str, str], Any]]:
         """Yield ``(labels_dict, instrument)`` for every series of name
-        across all three kinds."""
-        for store in (self._counters, self._gauges, self._hists):
+        of both kinds."""
+        for store in (self._counters, self._hists):
             for (n, key), obj in store.items():
                 if n == name:
                     yield dict(key), obj
@@ -181,8 +157,6 @@ class MetricsRegistry:
     def merge(self, other: "MetricsRegistry") -> "MetricsRegistry":
         for (n, key), c in other._counters.items():
             self._counters.setdefault((n, key), Counter()).value += c.value
-        for (n, key), g in other._gauges.items():
-            self._gauges.setdefault((n, key), Gauge()).value = g.value
         for (n, key), h in other._hists.items():
             mine = self._hists.get((n, key))
             if mine is None:
@@ -195,8 +169,6 @@ class MetricsRegistry:
             "schema_version": self.SCHEMA_VERSION,
             "counters": {_series_name(n, k): c.value
                          for (n, k), c in sorted(self._counters.items())},
-            "gauges": {_series_name(n, k): g.value
-                       for (n, k), g in sorted(self._gauges.items())},
             "histograms": {_series_name(n, k): h.to_dict()
                            for (n, k), h in sorted(self._hists.items())},
         }
@@ -207,9 +179,6 @@ class MetricsRegistry:
         for key, v in d.get("counters", {}).items():
             name, labels = _parse_series_name(key)
             reg.counter(name, **labels).value = int(v)
-        for key, v in d.get("gauges", {}).items():
-            name, labels = _parse_series_name(key)
-            reg.gauge(name, **labels).value = float(v)
         for key, hd in d.get("histograms", {}).items():
             name, labels = _parse_series_name(key)
             lk = (name, _label_key(labels))

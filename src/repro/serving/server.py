@@ -28,8 +28,11 @@ single-model micro-batcher cannot offer:
   ``kernels/forest_infer/ops.py``, so N routed models cost N uploads, not
   N × requests.
 * **Metrics** (§9.4): accepted/shed/timed-out/retried/fallback counters,
-  circuit transitions, per-bucket padding waste, and p50/p99 latency over a
-  bounded reservoir.
+  circuit transitions, per-bucket padding waste, p50/p99 latency and each
+  request's queue wait over bounded reservoirs. While tracing, every
+  ``submit`` (``server/submit``, with its ticket), ``pump``
+  (``server/pump``) and dispatch (``server/dispatch``, with its rows,
+  padded rows and tickets) is a span.
 
 The core is deliberately synchronous and clock-injected: driven by
 ``submit``/``pump``/``result`` it is deterministic under
@@ -48,6 +51,7 @@ from typing import Callable, Mapping
 import numpy as np
 
 from repro.core.api import EngineFailure, YdfError
+from repro.obs import trace
 from repro.obs.metrics import MetricsRegistry
 from repro.serving.forest import DEFAULT_BUCKETS, ForestServeBundle
 
@@ -154,6 +158,15 @@ _COUNTER_FIELDS = ("submitted", "accepted", "shed", "timed_out", "completed",
 LATENCY_OUTCOMES = ("completed", "timed_out", "shed")
 
 
+def _percentiles_ms(vals) -> dict:
+    if not vals:
+        return {"p50_ms": None, "p99_ms": None, "n": 0}
+    v = np.asarray(vals)
+    return {"p50_ms": round(float(np.percentile(v, 50)) * 1e3, 4),
+            "p99_ms": round(float(np.percentile(v, 99)) * 1e3, 4),
+            "n": len(v)}
+
+
 class ServerMetrics:
     """Serving counters + latency reservoirs (§9.4), a facade over one
     ``obs.metrics.MetricsRegistry`` (§13.4 — same schema as every other
@@ -176,6 +189,7 @@ class ServerMetrics:
             self.registry.counter(name)
         for oc in LATENCY_OUTCOMES:
             self.registry.histogram("latency_s", outcome=oc)
+        self.registry.histogram("queue_wait_s")
 
     # counter attributes proxy to registry series so `metrics.shed += 1`
     # call sites stay untouched while the data lives in one schema
@@ -219,6 +233,17 @@ class ServerMetrics:
         h.cap = self.max_latency_samples
         h.observe(float(seconds))
 
+    @property
+    def queue_wait(self):
+        """Each dispatched request's wait from submit to the start of its
+        dispatch, in seconds."""
+        return self.registry.histogram("queue_wait_s")
+
+    def observe_queue_wait(self, seconds: float) -> None:
+        h = self.queue_wait
+        h.cap = self.max_latency_samples
+        h.observe(float(seconds))
+
     def observe_dispatch(self, engine: str, rows: int, padded: int) -> None:
         self.dispatches += 1
         self.rows_dispatched += rows
@@ -229,13 +254,8 @@ class ServerMetrics:
                               bucket=int(padded)).inc(padded - rows)
 
     def latency_percentiles(self, outcome: str = "completed") -> dict:
-        vals = self.registry.histogram("latency_s", outcome=outcome).values
-        if not vals:
-            return {"p50_ms": None, "p99_ms": None, "n": 0}
-        lat = np.asarray(vals)
-        return {"p50_ms": round(float(np.percentile(lat, 50)) * 1e3, 4),
-                "p99_ms": round(float(np.percentile(lat, 99)) * 1e3, 4),
-                "n": len(lat)}
+        return _percentiles_ms(
+            self.registry.histogram("latency_s", outcome=outcome).values)
 
     def to_dict(self) -> dict:
         out = {k: getattr(self, k) for k in _COUNTER_FIELDS}
@@ -246,6 +266,7 @@ class ServerMetrics:
         out["latency_by_outcome"] = {
             oc: self.latency_percentiles(outcome=oc)
             for oc in LATENCY_OUTCOMES}
+        out["queue_wait"] = _percentiles_ms(self.queue_wait.values)
         return out
 
     def summary(self) -> str:
@@ -276,6 +297,11 @@ class ServerMetrics:
                 lines.append(f"  latency  : [{oc}] p50={ol['p50_ms']:.3f} ms "
                              f"p99={ol['p99_ms']:.3f} ms over {ol['n']} "
                              "requests (excluded from headline percentiles)")
+        qw = _percentiles_ms(self.queue_wait.values)
+        if qw["n"]:
+            lines.append(f"  queue    : wait p50={qw['p50_ms']:.3f} ms "
+                         f"p99={qw['p99_ms']:.3f} ms over {qw['n']} "
+                         "dispatched requests")
         for b, s in sorted(self.padding_by_bucket.items()):
             total = s["dispatches"] * b
             waste = s["pad_rows"] / total if total else 0.0
@@ -479,6 +505,16 @@ class ForestServer:
         ``None`` falls back to the server default (``None`` = no deadline).
         """
         st = self._state(model)
+        with trace.span("server/submit") as sp:
+            ticket = self._admit(st, batch, deadline_s)
+            if sp is not None:
+                sp.args["ticket"] = ticket
+        if pump and st.pending_rows() >= self.max_batch:
+            self.pump(model=st.name)
+        return ticket
+
+    def _admit(self, st: _ModelState, batch,
+               deadline_s: float | None) -> int:
         X = st.bundle(0).predictor.encode(batch)   # schema errors = caller's
         now = self._clock()
         if deadline_s is None:
@@ -510,8 +546,6 @@ class ForestServer:
         st.queue.append(_Request(ticket, st.name, X, deadline, now))
         self._ticket_model[ticket] = st.name
         self.metrics.accepted += 1
-        if pump and st.pending_rows() >= self.max_batch:
-            self.pump(model=st.name)
         return ticket
 
     # ------------------------------------------------------------ dispatch
@@ -595,6 +629,10 @@ class ForestServer:
         discarded, never delivered."""
         states = [self._state(model)] if model is not None \
             else list(self._states.values())
+        with trace.span("server/pump"):
+            return self._pump(states)
+
+    def _pump(self, states: list[_ModelState]) -> list[int]:
         resolved: list[int] = []
         for st in states:
             if not st.queue:
@@ -615,9 +653,15 @@ class ForestServer:
                     live.append(r)
             if not live:
                 continue
+            for r in live:
+                self.metrics.observe_queue_wait(now - r.t_submit)
             X = np.concatenate([r.X for r in live], axis=0)
             try:
-                out = self._predict_resilient(st, X)
+                with trace.span("server/dispatch", rows=len(X)) as sp:
+                    if sp is not None:
+                        sp.args["padded"] = st.bundle(0).padded_size(len(X))
+                        sp.args["tickets"] = [r.ticket for r in live]
+                    out = self._predict_resilient(st, X)
             except RequestFailed as e:
                 for r in live:
                     self.metrics.failed += 1

@@ -20,7 +20,7 @@ import math
 
 import numpy as np
 
-from repro.obs import build_training_logs
+from repro.obs import build_training_logs, trace
 from repro.core.api import Learner, Task, YdfError, register_learner
 from repro.core.hparams import IsolationForestHparams
 from repro.core.models import IsolationForestModel, _as_vertical, raw_matrix
@@ -88,18 +88,19 @@ class IsolationForestLearner(Learner):
 
     def train(self, dataset, valid=None, checkpoint=None) -> IsolationForestModel:
         hp: IsolationForestHparams = self.hparams
-        ds = _as_vertical(dataset)
-        label = self.label if self.label in ds.spec.columns else None
-        feats = ds.spec.feature_names(label)
-        if not feats:
-            raise YdfError("Isolation forest needs at least one feature.")
-        X = raw_matrix(ds, feats)
-        N = X.shape[0]
-        psi = max(2, min(int(hp.subsample_count), N))
-        depth_cap = int(hp.max_depth) or max(1, math.ceil(math.log2(psi)))
-        forest = empty_forest(hp.num_trees, 2 * psi + 1, 1,
-                              feature_names=feats)
-        forest.tree_class = None
+        with trace.span("learner/prepare", learner="isolation"):
+            ds = _as_vertical(dataset)
+            label = self.label if self.label in ds.spec.columns else None
+            feats = ds.spec.feature_names(label)
+            if not feats:
+                raise YdfError("Isolation forest needs at least one feature.")
+            X = raw_matrix(ds, feats)
+            N = X.shape[0]
+            psi = max(2, min(int(hp.subsample_count), N))
+            depth_cap = int(hp.max_depth) or max(1, math.ceil(math.log2(psi)))
+            forest = empty_forest(hp.num_trees, 2 * psi + 1, 1,
+                                  feature_names=feats)
+            forest.tree_class = None
         depth = 0
         for t in range(hp.num_trees):
             rng = np.random.default_rng((self.seed & 0xFFFFFFFF, 104729, t))

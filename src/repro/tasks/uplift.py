@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.obs import build_training_logs
+from repro.obs import build_training_logs, trace
 from repro.core.api import Learner, Task, YdfError, register_learner
 from repro.core.grower import GrowthParams, grow_trees, resolve_engine
 from repro.core.hparams import UpliftHparams
@@ -48,32 +48,33 @@ class UpliftTreesLearner(Learner):
 
     def train(self, dataset, valid=None, checkpoint=None) -> UpliftModel:
         hp: UpliftHparams = self.hparams
-        td = prepare_train_data(self, dataset, max_bins=hp.max_bins)
-        N, F = td.binned.codes.shape
-        t01 = td.treatment.astype(np.float64)
-        base_stats = np.stack([td.y * t01, t01,
-                               td.y * (1.0 - t01), np.ones(N)], 1)
+        with trace.span("learner/prepare", learner="uplift"):
+            td = prepare_train_data(self, dataset, max_bins=hp.max_bins)
+            N, F = td.binned.codes.shape
+            t01 = td.treatment.astype(np.float64)
+            base_stats = np.stack([td.y * t01, t01,
+                                   td.y * (1.0 - t01), np.ones(N)], 1)
 
-        if hp.num_candidate_attributes == "SQRT":
-            ratio = min(1.0, np.sqrt(F) / F)
-        elif hp.num_candidate_attributes == "ALL":
-            ratio = 1.0
-        else:
-            ratio = float(hp.num_candidate_attributes)
-        sp = SplitterParams(stat_kind="uplift", min_examples=hp.min_examples,
-                            num_candidate_ratio=ratio)
-        gp = GrowthParams(max_depth=hp.max_depth, max_nodes=hp.max_num_nodes,
-                          splitter=sp, engine=hp.growth_engine,
-                          histogram_backend=hp.histogram_backend,
-                          feature_sampling="keyed",
-                          sampling_key=self.seed & 0xFFFFFFFF)
-        engine_used, fallback = resolve_engine(gp, td.binned, False)
-        block = max(1, int(hp.tree_parallelism))
-        forest = empty_forest(hp.num_trees, hp.max_num_nodes, 1,
-                              feature_names=td.features)
-        forest.tree_class = None
-        tree_rng = [np.random.default_rng((self.seed & 0xFFFFFFFF, 104729, t))
-                    for t in range(hp.num_trees)]
+            if hp.num_candidate_attributes == "SQRT":
+                ratio = min(1.0, np.sqrt(F) / F)
+            elif hp.num_candidate_attributes == "ALL":
+                ratio = 1.0
+            else:
+                ratio = float(hp.num_candidate_attributes)
+            sp = SplitterParams(stat_kind="uplift", min_examples=hp.min_examples,
+                                num_candidate_ratio=ratio)
+            gp = GrowthParams(max_depth=hp.max_depth, max_nodes=hp.max_num_nodes,
+                              splitter=sp, engine=hp.growth_engine,
+                              histogram_backend=hp.histogram_backend,
+                              feature_sampling="keyed",
+                              sampling_key=self.seed & 0xFFFFFFFF)
+            engine_used, fallback = resolve_engine(gp, td.binned, False)
+            block = max(1, int(hp.tree_parallelism))
+            forest = empty_forest(hp.num_trees, hp.max_num_nodes, 1,
+                                  feature_names=td.features)
+            forest.tree_class = None
+            tree_rng = [np.random.default_rng((self.seed & 0xFFFFFFFF, 104729, t))
+                        for t in range(hp.num_trees)]
         for b0 in range(0, hp.num_trees, block):
             ts = list(range(b0, min(b0 + block, hp.num_trees)))
             counts_b = []

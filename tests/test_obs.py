@@ -128,6 +128,182 @@ def test_events_and_disabled_noop():
     trace.event("ignored")
 
 
+# ------------------------------------------- profiler annotations and hooks
+
+def _all_spans(tr):
+    return [s for r in tr.roots for s in r.walk()]
+
+
+def test_spans_land_on_profiler_host_plane(tmp_path):
+    """While tracing, each span is also a profiler annotation: it shows on
+    the profiler's host plane with its name, nested as in the tracer, and
+    with the Span's duration."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        with trace.capture() as tr:
+            with trace.span("obs_test/outer", step=1):
+                time.sleep(0.005)
+                with trace.span("obs_test/inner"):
+                    time.sleep(0.01)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [ev for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU" for line in plane.lines
+            for ev in line.events if ev.name.startswith("obs_test/")]
+    assert sorted(ev.name for ev in host) == ["obs_test/inner",
+                                              "obs_test/outer"]
+    got = {ev.name: ev for ev in host}
+    outer, inner = got["obs_test/outer"], got["obs_test/inner"]
+    assert outer.start_ns <= inner.start_ns
+    assert (inner.start_ns + inner.duration_ns
+            <= outer.start_ns + outer.duration_ns)
+    (o,) = [r for r in tr.roots if r.name == "obs_test/outer"]
+    (i,) = [c for c in o.children if c.name == "obs_test/inner"]
+    assert outer.duration_ns / 1e9 == pytest.approx(o.duration, abs=1e-3)
+    assert inner.duration_ns / 1e9 == pytest.approx(i.duration, abs=1e-3)
+
+
+def test_gc_hook_records_one_span_per_collection():
+    import gc
+    was_enabled = gc.isenabled()
+    gc.disable()               # only the forced collection below may run
+    try:
+        with trace.capture() as tr:
+            gc.collect()
+    finally:
+        if was_enabled:
+            gc.enable()
+    (sp,) = [s for s in _all_spans(tr) if s.name == "runtime/gc"]
+    assert sp.args["generation"] == 2
+    assert sp.args["collected"] >= 0
+    assert sp.duration >= 0
+    assert trace._on_gc not in gc.callbacks
+
+
+def test_compile_hook_records_a_new_shape_inside_its_span():
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((3, 7), jnp.float32)
+
+    def obs_hook_probe(a):
+        return a * 2.0 + 1.0
+
+    f = jax.jit(obs_hook_probe)
+    with trace.capture() as tr:
+        with trace.span("obs_test/call"):
+            f(x).block_until_ready()
+    (call,) = [r for r in tr.roots if r.name == "obs_test/call"]
+    comps = [s for s in call.walk() if s.name == "jax/compile"]
+    assert len(comps) == 1
+    (comp,) = comps
+    assert "obs_hook_probe" in comp.args["fun_name"]
+    assert call.t0 - 1e-3 <= comp.t0 <= comp.t1 <= call.t1 + 1e-3
+    # a second call at the same shape compiles nothing
+    with trace.capture() as tr2:
+        f(x).block_until_ready()
+    assert not [s for s in _all_spans(tr2) if s.name == "jax/compile"]
+
+
+def test_hooks_are_installed_only_while_a_tracer_is_active():
+    import gc
+
+    from jax._src import monitoring
+
+    def installed():
+        gc_on = trace._on_gc in gc.callbacks
+        jax_on = trace._on_compile in monitoring._event_time_span_listeners
+        assert gc_on == jax_on
+        return gc_on
+
+    assert not installed()
+    with trace.capture():
+        assert installed()
+        with trace.capture():
+            assert installed()
+        assert installed()             # the outer capture is still active
+    assert not installed()
+    trace.start()
+    assert installed()
+    trace.stop()
+    assert not installed()
+    trace.stop()                       # stopping twice removes nothing twice
+    assert not installed()
+
+
+def test_traced_predict_names_encode_dispatch_and_finalize(tiny_adult):
+    """The pallas engine's dispatch (interpret mode here) splits into the
+    batch's upload, the kernel with the reorder, and the copy back."""
+    from repro.core import GradientBoostedTreesLearner
+    from repro.core.engines import compile_predictor
+
+    model = GradientBoostedTreesLearner(label="income", num_trees=2,
+                                        max_depth=2).train(tiny_adult)
+    pred = compile_predictor(model, "pallas")
+    rows = {k: v[:16] for k, v in tiny_adult.items() if k != "income"}
+    want = pred.predict(rows)          # compiles outside the capture
+    with trace.capture() as tr:
+        got = pred.predict(rows)
+    np.testing.assert_array_equal(got, want)
+    tops = [r for r in tr.roots if r.name.startswith("engines/")]
+    assert [r.name for r in tops] == ["engines/encode", "engines/dispatch",
+                                      "engines/finalize"]
+    inner = [c for c in tops[1].children if c.name.startswith("engines/")]
+    assert [c.name for c in inner] == ["engines/upload", "engines/kernel",
+                                       "engines/to_host"]
+    assert inner[2].args["bytes"] == 16 * model.forest.n_trees * 4
+    assert tops[2].args["rows"] == 16
+
+
+@pytest.mark.parametrize("engine", ["batched", "device"])
+def test_traced_train_names_every_tree_boundary(tiny_adult, tmp_path,
+                                                engine):
+    """Each boosting iteration is a span holding the gradients, the
+    statistics, the tree, and the boundary after it (update, losses,
+    checkpoint); each job's preparation and finish have one; the device
+    grower adds its per-tree upload and decode, and the first tree holds
+    the one upload of the codes."""
+    from repro.core import GradientBoostedTreesLearner
+
+    n = 3
+    with trace.capture() as tr:
+        model = GradientBoostedTreesLearner(
+            label="income", num_trees=n, max_depth=2,
+            growth_engine=engine).train(tiny_adult,
+                                        checkpoint=str(tmp_path / "ck"))
+    assert model.training_logs["growth_engine"] == engine
+    names = [s.name for s in _all_spans(tr)]
+    for name in ("gbt/iteration", "gbt/grad_hess", "gbt/stats", "gbt/tree",
+                 "gbt/boundary", "gbt/update", "gbt/loss",
+                 "checkpoint/boundary"):
+        assert names.count(name) == n, name
+    assert names.count("learner/prepare") == 1
+    assert names.count("learner/finish") == 1
+    hooks = trace.HOOK_SPANS
+    tops = [r.name for r in tr.roots if r.name not in hooks]
+    assert tops == (["learner/prepare"] + ["gbt/iteration"] * n
+                    + ["learner/finish"])
+    for it in (r for r in tr.roots if r.name == "gbt/iteration"):
+        assert [c.name for c in it.children if c.name not in hooks] == [
+            "gbt/grad_hess", "gbt/stats", "gbt/tree", "gbt/boundary"]
+    (prep,) = [r for r in tr.roots if r.name == "learner/prepare"]
+    assert "grower/binning" in [s.name for s in prep.walk()]
+    for b in (s for s in _all_spans(tr) if s.name == "gbt/boundary"):
+        assert [c.name for c in b.children if c.name not in hooks] == [
+            "gbt/update", "gbt/loss", "checkpoint/boundary"]
+    device = ("grower_device/upload", "grower_device/decode")
+    for name in device:
+        assert names.count(name) == (n if engine == "device" else 0), name
+    first_tree = next(s for s in _all_spans(tr) if s.name == "gbt/tree")
+    codes = [s.name for s in first_tree.walk()].count("grower_device/codes")
+    assert codes == names.count("grower_device/codes")
+    assert codes == (1 if engine == "device" else 0)
+    assert "grower_device/host_sync" not in names
+
+
 # --------------------------------------------------------------- exporters
 
 def _sample_tracer():
@@ -181,8 +357,6 @@ def test_metrics_counters_gauges_histograms():
     assert reg.counter("requests").value == 3
     reg.counter("requests", engine="pallas").inc(5)
     assert reg.labeled_values("requests", "engine") == {"pallas": 5}
-    reg.gauge("queue_depth").set(7)
-    assert reg.gauge("queue_depth").value == 7
     h = reg.histogram("latency_s")
     for v in (1.0, 2.0, 3.0, 4.0):
         h.observe(v)
@@ -204,21 +378,18 @@ def test_registry_roundtrip_and_merge():
     a = obs_metrics.MetricsRegistry()
     a.counter("trees").inc(3)
     a.counter("dispatches", engine="numpy").inc(2)
-    a.gauge("depth").set(5)
     a.histogram("lat", outcome="ok").observe(1.5)
     d = a.to_dict()
     assert d["schema_version"] == 1
     json.dumps(d)
     b = obs_metrics.MetricsRegistry.from_dict(d)
     assert b.to_dict() == d                  # lossless round-trip
-    # merge: counters add, gauges last-write, histograms pool
+    # merge: counters add, histograms pool
     c = obs_metrics.MetricsRegistry()
     c.counter("trees").inc(3)
-    c.gauge("depth").set(9)
     c.histogram("lat", outcome="ok").observe(2.5)
     b.merge(c)
     assert b.counter("trees").value == 6
-    assert b.gauge("depth").value == 9
     h = b.histogram("lat", outcome="ok")
     assert h.count == 2 and h.mean == pytest.approx(2.0)
     assert b.counter("dispatches", engine="numpy").value == 2

@@ -409,6 +409,46 @@ def test_metrics_surface(trained):
     assert len(m._latencies) <= 64
 
 
+def test_queue_wait_and_server_spans(trained):
+    """Each dispatched request's queue wait is its submit-to-dispatch time,
+    exact under the fake clock; while tracing, the spans of a request
+    carry its ticket."""
+    from repro.obs import trace
+
+    gbt, feats = trained
+    clk = FakeClock()
+    srv = make_server(gbt, clk)
+    with trace.capture(clock=clk.now) as tr:
+        a = srv.submit(req_slice(feats, 0), pump=False)
+        clk.advance(0.003)
+        b = srv.submit(req_slice(feats, 8), pump=False)
+        clk.advance(0.002)
+        srv.pump()
+    wait = srv.metrics.queue_wait
+    assert wait.count == 2
+    assert sorted(wait.values) == pytest.approx([0.002, 0.005])
+    submits = [r for r in tr.roots if r.name == "server/submit"]
+    assert [s.args["ticket"] for s in submits] == [a, b]
+    (pump,) = [r for r in tr.roots if r.name == "server/pump"]
+    assert pump.t0 == pytest.approx(0.005)
+    (disp,) = [c for c in pump.children if c.name == "server/dispatch"]
+    assert disp.args == {"rows": 16, "padded": 16, "tickets": [a, b]}
+    # a shed request's span carries the error, and waits in no queue
+    srv2 = make_server(gbt, clk, max_queue_rows=4)
+    with trace.capture(clock=clk.now) as tr2:
+        with pytest.raises(RequestShed):
+            srv2.submit(req_slice(feats, 0))
+    (shed,) = tr2.roots
+    assert shed.name == "server/submit"
+    assert shed.args == {"error": "RequestShed"}
+    assert srv2.metrics.queue_wait.count == 0
+    # operators read the waits in to_dict() and summary()
+    qw = srv.metrics.to_dict()["queue_wait"]
+    assert qw["n"] == 2
+    assert qw["p50_ms"] == pytest.approx(3.5)
+    assert "queue    : wait p50=3.500 ms" in srv.metrics.summary()
+
+
 # ------------------------------------------------------------ async front-end
 
 def test_async_front_end_micro_batches_and_sheds(trained):

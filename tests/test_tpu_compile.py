@@ -55,10 +55,15 @@ def _no_persistent_cache():
         compilation_cache.reset_cache()
 
 
-def _compile_for_chip(fn, *args):
+def _compile_for_chip(fn, *args, kernel=None):
+    """Compile ``fn`` for the described chip; the Mosaic kernel's operation
+    must carry the name ``kernel``, which the benchmark's trace reduction
+    matches (``bench/lib/devtrace.py``)."""
     with _no_persistent_cache():
         text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    if kernel is not None:
+        assert f"%{kernel}" in text, f"no operation named {kernel!r}"
 
 
 @pytest.mark.parametrize("n_stats,kind", [(2, "gh"), (3, "class"),
@@ -82,7 +87,8 @@ def test_fused_split_kernel_compiles_for_v5e(one_chip, n_slots, n_stats,
                              sharding=one_chip),
         jax.ShapeDtypeStruct((N_ROWS, n_stats), jnp.float32,
                              sharding=one_chip),
-        jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip))
+        jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip),
+        kernel="fused_split_pallas")
 
 
 def test_histogram_kernel_compiles_for_v5e(one_chip):
@@ -97,7 +103,8 @@ def test_histogram_kernel_compiles_for_v5e(one_chip):
         jax.ShapeDtypeStruct((N_ROWS, N_FEATURES), jnp.uint8,
                              sharding=one_chip),
         jax.ShapeDtypeStruct((N_ROWS, 4), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip))
+        jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip),
+        kernel="histogram_pallas")
 
 
 def test_distributed_level_step_compiles_for_v5e_2x2(topo):
@@ -152,4 +159,5 @@ def test_forest_infer_kernel_compiles_for_v5e(one_chip):
 
     _compile_for_chip(infer, on_chip(jax.ShapeDtypeStruct((4096, 10),
                                                           jnp.float32)),
-                      on_chip(tbl), on_chip(leaf), on_chip(p.block_depth))
+                      on_chip(tbl), on_chip(leaf), on_chip(p.block_depth),
+                      kernel="forest_predict_pallas_tiled")
