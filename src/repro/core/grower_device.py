@@ -127,6 +127,7 @@ def _level_step(cfg: _StepConfig):
     from repro.kernels.histogram.fused import (
         NEG_INF,
         _numerical_gains,
+        fused_split_lane_major,
         fused_split_pallas,
         score_stats,
     )
@@ -143,32 +144,45 @@ def _level_step(cfg: _StepConfig):
             return h[..., 1] / n
         return h[..., 0] / n
 
-    def chunk_best(codes, nbins, iscat, stats, fsel_c, loc, w_slots):
+    def chunk_best(codes, codes_t, nbins, iscat, stats, fsel_c, loc, w_slots):
         """Best split per slot for one W-wide slot chunk.
 
-        codes (N, F) i32; stats (K, N, S) f32; fsel_c (K, W, kf) i32;
-        loc (K, N) i32 local slot in [-1, W). Returns per-(K, W): gain f32,
-        feature i32 (original column), split_bin i32, iscat bool, and the
-        (K, W, B) go-right-by-code table.
+        codes (N, F) i32; codes_t: codes in the fused kernel's (F, 1, Np)
+        layout (pallas/interpret without sampling), else None; stats
+        (K, N, S) f32; fsel_c (K, W, kf) i32; loc (K, N) i32 local slot in
+        [-1, W). Returns per-(K, W): gain f32, feature i32 (original column),
+        split_bin i32, iscat bool, and the (K, W, B) go-right-by-code table.
         """
         K, N = loc.shape
         act = loc >= 0
         locc = jnp.maximum(loc, 0)
-        # per-example candidate codes: codes[i, fsel_c[k, loc[k,i], j]]
-        with jax.named_scope("candidate_gather"):
-            fex = jnp.take_along_axis(
-                fsel_c, locc[:, :, None], axis=1)                 # (K, N, kf)
-            cex = codes[jnp.arange(N)[None, :, None], fex]        # (K, N, kf)
+        if cfg.sample:
+            # per-example candidate codes: codes[i, fsel_c[k, loc[k,i], j]]
+            with jax.named_scope("candidate_gather"):
+                fex = jnp.take_along_axis(
+                    fsel_c, locc[:, :, None], axis=1)             # (K, N, kf)
+                cex = codes[jnp.arange(N)[None, :, None], fex]    # (K, N, kf)
+            column = lambda j: cex[:, :, j]                       # (K, N)
+        else:
+            # every slot's candidates are features 0..F-1 in order, so the
+            # candidate codes are the table itself, shared by all K trees
+            column = lambda j: codes[None, :, j]                  # (1, N)
 
         if cfg.impl in ("pallas", "interpret"):
             # fused kernel: hist + numerical scan + argmax fully in VMEM
             with jax.named_scope("histogram"):
                 gains, js, sbins = [], [], []
                 for k in range(K):
-                    gk, jk, bk = fused_split_pallas(
-                        cex[k], stats[k], loc[k], w_slots,
-                        _B, kind=kind, l2=l2, min_examples=min_ex,
-                        interpret=(cfg.impl == "interpret"))
+                    if cfg.sample:
+                        gk, jk, bk = fused_split_pallas(
+                            cex[k], stats[k], loc[k], w_slots,
+                            _B, kind=kind, l2=l2, min_examples=min_ex,
+                            interpret=(cfg.impl == "interpret"))
+                    else:
+                        gk, jk, bk = fused_split_lane_major(
+                            codes_t, stats[k], loc[k], w_slots,
+                            _B, kind=kind, l2=l2, min_examples=min_ex,
+                            interpret=(cfg.impl == "interpret"))
                     gains.append(gk), js.append(jk), sbins.append(bk)
             with jax.named_scope("gain_scan"):
                 gain = jnp.stack(gains)                           # (K, W)
@@ -190,7 +204,7 @@ def _level_step(cfg: _StepConfig):
             ws = jnp.where(act[:, :, None], stats, 0.0)           # (K, N, S)
             hists = []
             for j in range(kf):
-                seg = jnp.where(act, locc * _B + cex[:, :, j], w_slots * _B)
+                seg = jnp.where(act, locc * _B + column(j), w_slots * _B)
                 h = jax.vmap(lambda s, v: jax.ops.segment_sum(
                     v, s, num_segments=w_slots * _B + 1))(seg, ws)
                 hists.append(h[:, :w_slots * _B].reshape(K, w_slots, _B, S))
@@ -268,7 +282,7 @@ def _level_step(cfg: _StepConfig):
         return gain, feat, sbin, iscat_w, tbl, ps
 
     @jax.jit
-    def step(codes, nbins, iscat, stats, tree_ids, slot_of, slot_node,
+    def step(codes, codes_t, nbins, iscat, stats, tree_ids, slot_of, slot_node,
              feat_a, sbin_a, catm_a, left_a, gain_a, lstats_a, nn, node_of,
              depth):
         K, P = slot_node.shape
@@ -291,7 +305,7 @@ def _level_step(cfg: _StepConfig):
         for g0 in range(0, P, W):
             loc = jnp.where((slot_of >= g0) & (slot_of < g0 + W),
                             slot_of - g0, -1)
-            outs.append(chunk_best(codes, nbins, iscat, stats,
+            outs.append(chunk_best(codes, codes_t, nbins, iscat, stats,
                                    fsel[:, g0:g0 + W], loc, W))
         gain, feat_w, sbin_w, iscat_w, tbl, ps = (
             jnp.concatenate([o[i] for o in outs], axis=1) if len(outs) > 1
@@ -361,16 +375,26 @@ def _next_pow2(x: int) -> int:
     return 1 << max(0, int(x) - 1).bit_length()
 
 
-def _device_codes(binned: BinnedFeatures):
-    """codes as a device int32 array, cached on the BinnedFeatures instance
-    (shared across trees, blocks, and boosting iterations)."""
+def _device_codes(binned: BinnedFeatures, lane_major: bool = False):
+    """codes as a device int32 array, n_bins, is_cat and, once asked for
+    with ``lane_major``, the codes in the fused kernel's (F, 1, Np) layout
+    (else None), which the unsampled fused level step reads as is. Cached
+    on the BinnedFeatures instance (shared across trees, blocks, and
+    boosting iterations); the layout is built on the device."""
+    import jax
     import jax.numpy as jnp
+    from repro.kernels.histogram.histogram import lane_major_codes
     cached = getattr(binned, "_device_codes", None)
-    if cached is None:
-        with trace.span("grower_device/codes", rows=binned.codes.shape[0]):
-            cached = (jnp.asarray(binned.codes.astype(np.int32)),
-                      jnp.asarray(binned.n_bins.astype(np.int32)),
-                      jnp.asarray(binned.is_cat))
+    if cached is None or (lane_major and cached[3] is None):
+        with trace.span("grower_device/codes", rows=binned.codes.shape[0],
+                        lane_major=lane_major):
+            if cached is None:
+                cached = (jnp.asarray(binned.codes.astype(np.int32)),
+                          jnp.asarray(binned.n_bins.astype(np.int32)),
+                          jnp.asarray(binned.is_cat), None)
+            if lane_major:
+                cached = cached[:3] + (jax.block_until_ready(
+                    lane_major_codes(cached[0])),)
         binned._device_codes = cached
     return cached
 
@@ -406,7 +430,8 @@ def grow_trees_device(forest: Forest, ts, binned: BinnedFeatures,
         F=F, S=S, M=M, max_nodes=int(params.max_nodes), impl=impl)
     step = _level_step(cfg)
 
-    codes, nbins, iscat = _device_codes(binned)
+    codes, nbins, iscat, codes_t = _device_codes(
+        binned, lane_major=impl != "jnp" and not cfg.sample)
     with trace.span("grower_device/upload", trees=Kr, rows=N):
         stats_np = np.zeros((K, N, S), np.float32)
         act_np = np.zeros((K, N), bool)
@@ -440,17 +465,18 @@ def grow_trees_device(forest: Forest, ts, binned: BinnedFeatures,
             first = shape_key not in _stepped_shapes
             _stepped_shapes.add(shape_key)
             with trace.span("grower_device/level_step", level=_level,
-                            P=int(slot_node.shape[1]), compile=first):
+                            P=int(slot_node.shape[1]), compile=first,
+                            candidates="sampled" if cfg.sample else "all"):
                 out = step(
-                    codes, nbins, iscat, stats, tree_ids, slot_of,
+                    codes, codes_t, nbins, iscat, stats, tree_ids, slot_of,
                     slot_node, feat_a, sbin_a, catm_a, left_a, gain_a,
                     lstats_a, nn, node_of, depth)
                 jax.block_until_ready(out)
         else:
             out = step(
-                codes, nbins, iscat, stats, tree_ids, slot_of, slot_node,
-                feat_a, sbin_a, catm_a, left_a, gain_a, lstats_a, nn,
-                node_of, depth)
+                codes, codes_t, nbins, iscat, stats, tree_ids, slot_of,
+                slot_node, feat_a, sbin_a, catm_a, left_a, gain_a, lstats_a,
+                nn, node_of, depth)
         (slot_of, slot_node, feat_a, sbin_a, catm_a, left_a, gain_a,
          lstats_a, nn, node_of, depth, nv) = out
         # the single per-level host sync: the compacted frontier width,

@@ -44,9 +44,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.histogram.histogram import (
     _HIGHEST,
+    TILE_N,
     _round_up,
     accumulate_tile,
-    transpose_inputs,
+    lane_major_codes,
+    transpose_rows,
 )
 
 NEG_INF = -1e30  # matches splitters.NEG_INF
@@ -173,21 +175,24 @@ def _fused_kernel(codes_ref, stats_ref, slot_ref, gain_ref, feat_ref, bin_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "n_slots", "n_bins", "kind", "l2", "min_examples", "tile_n", "interpret"))
-def fused_split_pallas(codes: jax.Array, stats: jax.Array, slot_of: jax.Array,
-                       n_slots: int, n_bins: int = 256, *, kind: str = "gh",
-                       l2: float = 0.0, min_examples: int = 5,
-                       tile_n: int = 512, interpret: bool = False):
-    """codes: (N, kf) integer numerical bin codes, one column per candidate
-    feature; stats: (N, S) f32; slot_of: (N,) int32 in [-1, n_slots).
-    -> (gain (n_slots,) f32, feature-column (n_slots,) i32, split_bin
-    (n_slots,) i32). feature == -1 when no position was scoreable."""
-    kf = codes.shape[1]
+def fused_split_lane_major(codes_t: jax.Array, stats: jax.Array,
+                           slot_of: jax.Array, n_slots: int,
+                           n_bins: int = 256, *, kind: str = "gh",
+                           l2: float = 0.0, min_examples: int = 5,
+                           tile_n: int = TILE_N, interpret: bool = False):
+    """``fused_split_pallas`` on codes already in the kernel's layout:
+    codes_t (kf, 1, Np) int32 from ``lane_major_codes(codes, tile_n)``,
+    taken as is; only the stats and slot ids are transposed here."""
+    kf = codes_t.shape[0]
     S = stats.shape[1]
     # sublane-aligned slot axis, in whole 128-row scan blocks past 128
     Wp = _round_up(n_slots, 8 if n_slots <= 128 else 128)
-    codes_t, stats_t, slot_t, TN = transpose_inputs(codes, stats, slot_of,
-                                                    tile_n)
-    n_tiles = codes_t.shape[-1] // TN
+    stats_t, slot_t, TN = transpose_rows(stats, slot_of, tile_n)
+    if codes_t.shape[1:] != (1, stats_t.shape[-1]):
+        raise ValueError(
+            f"codes_t {codes_t.shape} is not lane_major_codes of "
+            f"{stats.shape[0]} rows at tile_n={tile_n}")
+    n_tiles = stats_t.shape[-1] // TN
     kernel = functools.partial(
         _fused_kernel, n_slots=Wp, n_bins=n_bins, n_tiles=n_tiles, kind=kind,
         l2=float(l2), min_examples=int(min_examples))
@@ -209,3 +214,19 @@ def fused_split_pallas(codes: jax.Array, stats: jax.Array, slot_of: jax.Array,
         name="fused_split_pallas",
     )(codes_t, stats_t, slot_t)
     return gain[:n_slots, 0], feat[:n_slots, 0], sbin[:n_slots, 0]
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_slots", "n_bins", "kind", "l2", "min_examples", "tile_n", "interpret"))
+def fused_split_pallas(codes: jax.Array, stats: jax.Array, slot_of: jax.Array,
+                       n_slots: int, n_bins: int = 256, *, kind: str = "gh",
+                       l2: float = 0.0, min_examples: int = 5,
+                       tile_n: int = TILE_N, interpret: bool = False):
+    """codes: (N, kf) integer numerical bin codes, one column per candidate
+    feature; stats: (N, S) f32; slot_of: (N,) int32 in [-1, n_slots).
+    -> (gain (n_slots,) f32, feature-column (n_slots,) i32, split_bin
+    (n_slots,) i32). feature == -1 when no position was scoreable."""
+    return fused_split_lane_major(
+        lane_major_codes(codes, tile_n), stats, slot_of, n_slots, n_bins,
+        kind=kind, l2=l2, min_examples=min_examples, tile_n=tile_n,
+        interpret=interpret)

@@ -58,17 +58,36 @@ def accumulate_tile(codes, slot, stats, acc_ref, *, n_slots: int,
             preferred_element_type=jnp.float32)                 # (W, B) MXU
 
 
-def transpose_inputs(codes, stats, slot_of, tile_n: int):
-    """(N, F) codes, (N, S) stats, (N,) slots -> lane-major, tile-padded
-    (F, 1, Np) int32, (S, Np) f32, (1, Np) int32 (padding rows get slot -1),
-    plus the example tile TN (a multiple of 128)."""
+TILE_N = 512      # example tile (lanes) of both kernels
+
+
+def _example_tiling(n: int, tile_n: int) -> tuple[int, int]:
+    """The example tile TN (a multiple of 128) for ``n`` examples, and the
+    rows padded to whole tiles."""
+    TN = min(_round_up(tile_n, 128), _round_up(max(n, 1), 128))
+    return TN, _round_up(max(n, 1), TN)
+
+
+@functools.partial(jax.jit, static_argnames=("tile_n",))
+def lane_major_codes(codes, tile_n: int = TILE_N):
+    """(N, F) codes -> the kernels' lane-major (F, 1, Np) int32 layout, rows
+    padded with code 0 to whole example tiles. The device grower caches this
+    once per table; the kernels' (N, F) entry points build it per call."""
     N = codes.shape[0]
-    TN = min(_round_up(tile_n, 128), _round_up(max(N, 1), 128))
-    pad = _round_up(max(N, 1), TN) - N
-    codes_t = jnp.pad(codes.astype(jnp.int32).T, ((0, 0), (0, pad)))
-    stats_t = jnp.pad(stats.astype(jnp.float32).T, ((0, 0), (0, pad)))
-    slot_t = jnp.pad(slot_of.astype(jnp.int32), (0, pad), constant_values=-1)
-    return codes_t[:, None, :], stats_t, slot_t[None, :], TN
+    _, Np = _example_tiling(N, tile_n)
+    return jnp.pad(codes.astype(jnp.int32).T, ((0, 0), (0, Np - N)))[:, None]
+
+
+def transpose_rows(stats, slot_of, tile_n: int = TILE_N):
+    """(N, S) stats, (N,) slots -> lane-major, tile-padded (S, Np) f32 and
+    (1, Np) int32 (padding rows get slot -1, which never matches), plus the
+    example tile TN."""
+    N = stats.shape[0]
+    TN, Np = _example_tiling(N, tile_n)
+    stats_t = jnp.pad(stats.astype(jnp.float32).T, ((0, 0), (0, Np - N)))
+    slot_t = jnp.pad(slot_of.astype(jnp.int32), (0, Np - N),
+                     constant_values=-1)
+    return stats_t, slot_t[None, :], TN
 
 
 def _hist_kernel(codes_ref, stats_ref, node_ref, out_ref, *, n_nodes: int,
@@ -84,15 +103,15 @@ def _hist_kernel(codes_ref, stats_ref, node_ref, out_ref, *, n_nodes: int,
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "tile_n",
                                              "interpret"))
 def histogram_pallas(codes: jax.Array, stats: jax.Array, node_of: jax.Array,
-                     n_nodes: int, n_bins: int = 256, tile_n: int = 512,
+                     n_nodes: int, n_bins: int = 256, tile_n: int = TILE_N,
                      interpret: bool = False) -> jax.Array:
     """codes: (N, F) integer bin codes; stats: (N, S) f32; node_of: (N,)
     int32 (-1 = inactive). -> (n_nodes, F, B, S) f32."""
     F = codes.shape[1]
     S = stats.shape[1]
     Wn = _round_up(n_nodes, 8)          # sublane-aligned node axis
-    codes_t, stats_t, node_t, TN = transpose_inputs(codes, stats, node_of,
-                                                    tile_n)
+    codes_t = lane_major_codes(codes, tile_n)
+    stats_t, node_t, TN = transpose_rows(stats, node_of, tile_n)
     out = pl.pallas_call(
         functools.partial(_hist_kernel, n_nodes=Wn, n_bins=n_bins),
         grid=(F, codes_t.shape[-1] // TN),

@@ -94,3 +94,50 @@ def tiny_adult():
     """A small mixed-semantics training set shared by model-layer tests."""
     from repro.data.tabular import adult_like
     return adult_like(400, seed=3)
+
+
+# ------------------------------- device grower's level step, lowered alone
+
+def _level_step_program(impl, sample, N, F, K=1, P=32, sharding=None):
+    """The device grower's jitted level step for a numerical GBT table of
+    ``N`` x ``F`` codes, with ``K`` trees and ``P`` frontier slots, and the
+    shapes of its arguments (placed on ``sharding`` when given). ``sample``
+    keeps half the features per node."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.grower_device import _level_step, _StepConfig
+    from repro.core.sampling import sample_size
+    from repro.core.tree import MASK_WORDS
+    from repro.kernels.histogram.histogram import TILE_N, _example_tiling
+
+    S, M = 4, 127
+    cfg = _StepConfig(kind="gh", l2=0.0, min_examples=5, min_gain=0.0,
+                      cat_mode="none", sample=sample, sampling_key=7,
+                      kf=sample_size(0.5, F) if sample else F, F=F, S=S,
+                      M=M, max_nodes=M, impl=impl)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    codes_t = None
+    if impl != "jnp" and not sample:
+        codes_t = arg((F, 1, _example_tiling(N, TILE_N)[1]), jnp.int32)
+    i32, f32 = jnp.int32, jnp.float32
+    args = (arg((N, F), i32), codes_t, arg((F,), i32), arg((F,), jnp.bool_),
+            arg((K, N, S), f32), arg((K,), i32), arg((K, N), i32),
+            arg((K, P), i32), arg((K, M), i32), arg((K, M), i32),
+            arg((K, M, MASK_WORDS), jnp.uint32), arg((K, M), i32),
+            arg((K, M), f32), arg((K, M, S), f32), arg((K,), i32),
+            arg((K, N), i32), arg((K,), i32))
+    return _level_step(cfg), args
+
+
+def _gather_sizes(hlo_text):
+    """Element count of each ``gather`` result in an HLO module's text."""
+    import re
+    sizes = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\][^=\n]* gather\(", hlo_text):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        sizes.append(int(np.prod(dims)) if dims else 1)
+    return sizes

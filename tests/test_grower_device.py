@@ -308,34 +308,68 @@ def test_forced_pallas_without_device_raises():
 # ========================================== interpret-mode CI smoke
 
 
-def test_device_engine_interpret_smoke():
-    """One tiny numerical-only tree through the device engine with the fused
-    Pallas kernel in interpret mode — the tier-1 canary for kernel
-    regressions on CPU-only CI (JAX_PLATFORMS=cpu)."""
-    rng = np.random.default_rng(3)
-    N, F = 200, 3
+def _numerical_table(N, F, seed):
+    """Raw columns and their 32-bin codes: a numerical-only table."""
+    rng = np.random.default_rng(seed)
     X = rng.normal(size=(N, F))
-    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float64)
     bounds = [np.sort(rng.choice(np.unique(X[:, j]), 31, replace=False))
               for j in range(F)]
     codes = np.stack([np.searchsorted(bounds[j], X[:, j], side="left")
                       for j in range(F)], 1).astype(np.uint8)
-    binned = BinnedFeatures(
+    return X, BinnedFeatures(
         codes=codes, n_bins=np.array([32] * F, np.int32),
         is_cat=np.zeros(F, bool),
         boundaries=[b.astype(np.float32) for b in bounds],
         names=[f"f{j}" for j in range(F)])
-    stats = np.stack([y, np.ones(N), np.ones(N), np.ones(N)], 1)
-    leaf_fn = lambda s: np.array([s[0] / max(s[-1], 1e-12)], np.float32)
+
+
+@pytest.mark.parametrize("N,F,trees,block", [
+    (200, 3, 1, None),    # one GBT tree, one example tile
+    (1300, 5, 1, None),   # three 512-row tiles, the last one padded
+    (700, 4, 3, 4),       # RF, all features: 3 trees in a 4-tree block
+])
+def test_device_engine_interpret_smoke(N, F, trees, block):
+    """Numerical-only trees through the device engine with the fused Pallas
+    kernel in interpret mode — the tier-1 canary for kernel regressions on
+    CPU-only CI (JAX_PLATFORMS=cpu). Every feature is a candidate, so the
+    kernel reads the table's cached lane-major codes: padded past N to whole
+    example tiles, and shared by the lockstep block's trees (padded past the
+    real ones). Structure and routing equal the batched engine's."""
+    from repro.core.grower import grow_trees
+
+    X, binned = _numerical_table(N, F, seed=3)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(np.float64)
+    if trees == 1:
+        kind = "gh"
+        stats_list = [np.stack([y, np.ones(N), np.ones(N), np.ones(N)], 1)]
+        leaf_fn = lambda s: np.array([s[0] / max(s[-1], 1e-12)], np.float32)
+    else:
+        kind = "class"
+        rng = np.random.default_rng(5)
+        counts = [rng.multinomial(N, np.full(N, 1.0 / N)).astype(np.float64)
+                  for _ in range(trees)]
+        stats_list = [np.stack([(y == 0) * c, (y == 1) * c, c], 1)
+                      for c in counts]
+        leaf_fn = lambda s: (s[:-1] / max(s[-1], 1e-12)).astype(np.float32)
+    actives = [s[:, -1] > 0 for s in stats_list]
+    out_dim = len(leaf_fn(stats_list[0][0]))
 
     def grow(engine, impl="auto"):
-        forest = empty_forest(1, 64, 1, feature_names=binned.names)
+        forest = empty_forest(trees, 64, out_dim, feature_names=binned.names)
         gp = GrowthParams(max_depth=3, max_nodes=64,
-                          splitter=SplitterParams(stat_kind="gh",
+                          splitter=SplitterParams(stat_kind=kind,
                                                   min_examples=5),
-                          engine=engine, device_impl=impl)
-        node_of = grow_tree(forest, 0, binned, X, stats, np.ones(N, bool),
-                            leaf_fn, gp, np.random.default_rng(0))
+                          engine=engine, device_impl=impl,
+                          feature_sampling="keyed", sampling_key=11)
+        if trees == 1:
+            node_of = grow_tree(forest, 0, binned, X, stats_list[0],
+                                actives[0], leaf_fn, gp,
+                                np.random.default_rng(0))
+        else:
+            node_of = grow_trees(
+                forest, list(range(trees)), binned, X, stats_list, actives,
+                leaf_fn, gp, [np.random.default_rng(t) for t in range(trees)],
+                block=block)
         return forest, node_of
 
     fb, nb = grow("batched")
@@ -345,7 +379,25 @@ def test_device_engine_interpret_smoke():
                                       err_msg=f"forest.{k}")
     np.testing.assert_array_equal(nb, nd)
     np.testing.assert_allclose(fb.leaf_value, fd.leaf_value, atol=1e-5)
-    assert fd.n_nodes[0] > 1, "smoke tree did not grow"
+    assert (fd.n_nodes > 1).all(), "a tree did not grow"
+
+
+@pytest.mark.parametrize("sample", [False, True])
+def test_unsampled_level_step_gathers_no_candidate_codes(sample):
+    """With every feature a candidate at every node, the jnp level step
+    reads the codes in place: its compiled CPU program holds no gather of
+    N·F elements. With per-node sampling the per-example (N, kf) candidate
+    gather is still there."""
+    from conftest import _gather_sizes, _level_step_program
+    from repro.core.sampling import sample_size
+
+    N, F = 16384, 28
+    step, args = _level_step_program("jnp", sample, N, F)
+    sizes = _gather_sizes(step.lower(*args).compile().as_text())
+    if sample:
+        assert N * sample_size(0.5, F) in sizes, sizes
+    else:
+        assert sizes and max(sizes) < N * F, sizes
 
 
 def test_resolve_engine_reports_fallback():

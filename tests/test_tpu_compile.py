@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from conftest import _make_random_forest
+from conftest import _gather_sizes, _level_step_program, _make_random_forest
 
 N_ROWS = 1 << 18           # HIGGS-shaped training set cut to one chip
 N_FEATURES = 28
@@ -89,6 +89,20 @@ def test_fused_split_kernel_compiles_for_v5e(one_chip, n_slots, n_stats,
                              sharding=one_chip),
         jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip),
         kernel="fused_split_pallas")
+
+
+def test_unsampled_level_step_compiles_for_v5e(one_chip):
+    """The device grower's whole level step on the fused kernel, every
+    feature a candidate (GBT's default): one tree, 32 frontier slots. The
+    kernel reads the cached lane-major codes, so the program gathers no
+    (1, N, F) candidate codes."""
+    step, args = _level_step_program("pallas", False, N_ROWS, N_FEATURES,
+                                     K=1, P=32, sharding=one_chip)
+    with _no_persistent_cache():
+        text = step.lower(*args).compile().as_text()
+    assert "%fused_split_pallas" in text, "no fused split kernel"
+    sizes = _gather_sizes(text)
+    assert max(sizes, default=0) < N_ROWS * N_FEATURES, sizes
 
 
 def test_histogram_kernel_compiles_for_v5e(one_chip):
