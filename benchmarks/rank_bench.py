@@ -1,22 +1,17 @@
-"""Ranking-gradient benchmark: the group-batched LambdaMART lambda pass vs
-a per-group Python loop. Writes BENCH_rank.json (DESIGN.md §12).
+"""Ranking-gradient benchmark: the device LambdaMART lambda pass vs a
+per-group Python loop. Writes BENCH_rank.json (DESIGN.md §12).
 
-"naive"   = one `_lambda_pass` call per group at the group's own (m_g, m_g)
-pair-matrix size — the textbook implementation shape, dominated by Python
-dispatch and tiny-kernel overhead.
-"batched" = every group padded into ONE (groups, max_group, max_group)
-stack and swept in a single vectorized pass (tasks/ranking.py) — the form
-the GBT training loop actually runs each boosting iteration.
+"naive"  = the float64 oracle: one all-pairs `_lambda_pass` call per group
+at the group's own (m_g, m_g) size, dominated by Python dispatch and
+tiny-kernel overhead.
+"device" = the pass the GBT training loop runs each boosting iteration:
+groups laid out in power-of-two size buckets and swept by one jitted
+float32 program over the pairs that touch the top k (tasks/ranking.py).
 
-Both paths share the same kernel, so agreement is exact up to padding: the
-bench asserts max |Δ| <= 1e-12 on gradients AND hessians (at equal padded
-widths the two are bit-identical — pinned in tests/test_tasks.py).
-
-The win is shape-dependent and reported per shape, not hidden: with
-near-uniform group sizes (the common retrieval case — a fixed candidate
-count per query) the batched pass wins by >5x; heavy size skew pads every
-group to the largest and the O(max^2) waste can hand the round back to the
-loop. The headline tracks the uniform shape the GBT ranking loop targets.
+The bench checks that the two agree within float32's tolerance (the one
+tests/test_tasks.py states) on gradients AND hessians. Its times come from
+whatever backend JAX runs here: on a CPU host they are portability checks,
+not device speed.
 
 Usage: python -m benchmarks.rank_bench [--groups N] [--reps R] [--out PATH]
        [--quick]   (tiny smoke sizes; also exercised inside tier-1 tests)
@@ -30,8 +25,11 @@ import time
 
 import numpy as np
 
-from repro.tasks.ranking import group_layout, lambda_grad_batched, \
+from repro.tasks.ranking import group_layout, lambda_grad_device, \
     lambda_grad_naive
+
+# float32 against float64: the tolerance tests/test_tasks.py derives
+RTOL, ATOL_OF_SCALE = 1e-5, 4e-6
 
 
 def _make_groups(n_groups: int, lo: int, hi: int, seed: int):
@@ -39,7 +37,7 @@ def _make_groups(n_groups: int, lo: int, hi: int, seed: int):
     sizes = rng.integers(lo, hi + 1, n_groups)
     groups = np.repeat(np.arange(n_groups), sizes)
     n = len(groups)
-    scores = rng.normal(size=n)
+    scores = rng.normal(size=n).astype(np.float32).astype(np.float64)
     rel = rng.integers(0, 5, n).astype(np.float64)
     return groups, scores, rel
 
@@ -74,33 +72,36 @@ def run(n_groups: int = 1500, reps: int = 3, verbose: bool = True) -> dict:
         k = 5
         fns = [
             lambda: lambda_grad_naive(scores, rel, layout, k=k),
-            lambda: lambda_grad_batched(scores, rel, layout, k=k),
+            lambda: lambda_grad_device(scores, rel, layout, k=k),
         ]
-        times, (naive, batched) = _best_of(fns, reps)
-        dg = float(np.abs(naive[0] - batched[0]).max())
-        dh = float(np.abs(naive[1] - batched[1]).max())
+        lambda_grad_device(scores, rel, layout, k=k)     # compile
+        times, (naive, device) = _best_of(fns, reps)
+        agree = all(np.allclose(d, n, rtol=RTOL,
+                                atol=ATOL_OF_SCALE * np.abs(n).max())
+                    for d, n in zip(device, naive))
         row = {
             "n_groups": layout.n_groups,
             "n_rows": layout.n_rows,
-            "max_group": layout.max_size,
+            "max_group": int(layout.sizes.max()),
+            "bucket_widths": layout.widths,
             "ms_naive": round(times[0] * 1e3, 3),
-            "ms_batched": round(times[1] * 1e3, 3),
+            "ms_device": round(times[1] * 1e3, 3),
             "speedup": round(times[0] / times[1], 3),
-            "max_abs_diff_grad": dg,
-            "max_abs_diff_hess": dh,
-            "agree_1e12": bool(dg <= 1e-12 and dh <= 1e-12),
+            "max_abs_diff_grad": float(np.abs(naive[0] - device[0]).max()),
+            "max_abs_diff_hess": float(np.abs(naive[1] - device[1]).max()),
+            "agree_f32": bool(agree),
         }
         out["configs"][name] = row
         if verbose:
             print(f"  {name:14s} groups={row['n_groups']:<6d} "
                   f"rows={row['n_rows']:<7d} naive={row['ms_naive']:8.2f} ms  "
-                  f"batched={row['ms_batched']:8.2f} ms  "
+                  f"device={row['ms_device']:8.2f} ms  "
                   f"speedup={row['speedup']:6.2f}x  "
-                  f"agree<=1e-12={row['agree_1e12']}", flush=True)
+                  f"agree(f32)={row['agree_f32']}", flush=True)
     out["headline_speedup"] = max(
         c["speedup"] for c in out["configs"].values())
-    out["all_agree_1e12"] = all(
-        c["agree_1e12"] for c in out["configs"].values())
+    out["all_agree_f32"] = all(
+        c["agree_f32"] for c in out["configs"].values())
     return out
 
 
@@ -122,9 +123,9 @@ def main():
                                              reps=args.reps)
     with open(args.out, "w") as f:
         json.dump(res, f, indent=2)
-    print(f"headline (group-batched lambda pass vs per-group loop): "
-          f"{res['headline_speedup']:.2f}x, agreement<=1e-12: "
-          f"{res['all_agree_1e12']} -> {args.out}")
+    print(f"headline (device lambda pass vs per-group loop): "
+          f"{res['headline_speedup']:.2f}x, agreement within float32: "
+          f"{res['all_agree_f32']} -> {args.out}")
 
 
 if __name__ == "__main__":

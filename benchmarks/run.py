@@ -16,7 +16,7 @@
   analyze_bench   — DESIGN.md §8 permutation importance: compiled
                     batched-replica path vs naive per-feature loop
                     (BENCH_analyze.json when run as a module; quick here)
-  rank_bench      — DESIGN.md §12 group-batched LambdaMART lambda pass vs
+  rank_bench      — DESIGN.md §12 device LambdaMART lambda pass vs
                     per-group loop (BENCH_rank.json when run as a module)
   serve_bench     — DESIGN.md §9 fault-tolerant front-end: p50/p99 latency
                     vs offered QPS, clean vs fault-injected
@@ -115,8 +115,9 @@ def main() -> None:
     if "rank" not in args.skip:
         print("== LambdaMART lambda pass (DESIGN.md §12) ==", flush=True)
         res = rank_bench.run(n_groups=400, reps=2)
-        print(f"  headline: {res['headline_speedup']:.2f}x group-batched vs "
-              f"per-group loop, agreement<=1e-12: {res['all_agree_1e12']} "
+        print(f"  headline: {res['headline_speedup']:.2f}x device pass vs "
+              f"per-group loop, agreement within float32: "
+              f"{res['all_agree_f32']} "
               "(full run: python -m benchmarks.rank_bench)")
     if "distributed" not in args.skip:
         print("== distributed DF traffic (paper §3.9) ==", flush=True)

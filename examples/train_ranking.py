@@ -25,11 +25,14 @@ train = {k: v[tr_idx] for k, v in ds.items()}
 test = {k: v[te_idx] for k, v in ds.items()}
 
 # 3. task=RANKING routes the stock GBT grower through LambdaMARTLoss:
-#    pairwise |delta-NDCG@k|-weighted gradients computed as ONE padded
-#    (groups, max, max) pass (benchmarks/rank_bench.py measures it)
+#    pairwise |delta-NDCG@k|-weighted gradients computed by one jitted
+#    pass over size-bucketed groups, on the pairs that touch the top k
+#    (benchmarks/rank_bench.py checks it against the all-pairs oracle)
 model = GradientBoostedTreesLearner(label="rel", task=Task.RANKING,
                                     num_trees=80, seed=1).train(train)
 print(model.summary())
+print("lambda pass:", model.training_logs["ranking_pass"], "buckets",
+      model.training_logs["ranking_bucket_widths"])
 
 # 4. evaluate: NDCG@{1,5,10} through the task-aware evaluator, and the
 #    same number recomputed directly to show there is no magic

@@ -76,8 +76,14 @@ def bin_features(ds: VerticalDataset, features: list[str], *,
             v = np.minimum(v, max_bins - 1)
             codes[:, j] = v.astype(np.uint8)
             n_bins[j] = int(v.max()) + 1 if v.size else 1
-            is_cat[j] = True
-            boundaries.append(None)
+            if col.semantic == Semantic.BOOLEAN:
+                # false < true: an ordered feature whose one split, x >= 0.5,
+                # every grower, the fused kernel and every engine read as
+                # numerical
+                boundaries.append(np.array([0.5], np.float32)[:n_bins[j] - 1])
+            else:
+                is_cat[j] = True
+                boundaries.append(None)
     return BinnedFeatures(codes=codes, n_bins=n_bins, is_cat=is_cat,
                           boundaries=boundaries, names=list(features))
 

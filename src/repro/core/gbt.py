@@ -271,7 +271,9 @@ class GradientBoostedTreesLearner(Learner):
                 resilience=sess.events if sess is not None else None,
                 interrupted=interrupted,
                 extra={"train_loss": train_losses, "valid_loss": valid_losses,
-                       **engine_details(gp, td.binned, engine_used)})
+                       **engine_details(gp, td.binned, engine_used),
+                       **(loss.training_logs()
+                          if self.task == Task.RANKING else {})})
         return model
 
 

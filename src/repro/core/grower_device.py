@@ -49,8 +49,9 @@ _B = 256          # bin axis (uint8 codes)
 _W_CAP = 512      # per-chunk slot width inside the level step
 
 # (cfg, K, N, P) shape buckets whose level step has already been jitted in
-# this process — lets tracing label the first call at a bucket as compile
-# time and the rest as execute time (DESIGN.md §13.2).
+# this process — lets the grower compile every frontier width with the
+# first tree, and tracing label a first call at a bucket as compile time
+# and the rest as execute time (DESIGN.md §13.2).
 _stepped_shapes: set = set()
 
 
@@ -454,16 +455,32 @@ def grow_trees_device(forest: Forest, ts, binned: BinnedFeatures,
     nn = jnp.ones((K,), jnp.int32)
     depth = jnp.zeros((K,), jnp.int32)
 
+    # Every frontier width a tree of this shape can reach (P = 2, 4, ...,
+    # 2^(max_depth - 1), within the node capacity) compiles with the first
+    # tree: a width that a later tree reached first would compile there, a
+    # stall of seconds on the chip between two trees. The warm-up runs the
+    # step once per width on the root's state and drops what it returns.
+    widths = [P for P in (1 << i for i in range(1, params.max_depth))
+              if 2 * P <= M and (cfg, K, N, P) not in _stepped_shapes]
+    if widths:
+        with trace.span("grower_device/warm_up", widths=widths):
+            for P in widths:
+                _stepped_shapes.add((cfg, K, N, P))
+                jax.block_until_ready(step(
+                    codes, codes_t, nbins, iscat, stats, tree_ids, slot_of,
+                    jnp.zeros((K, P), jnp.int32), feat_a, sbin_a, catm_a,
+                    left_a, gain_a, lstats_a, nn, node_of, depth))
+
     for _level in range(params.max_depth):
         # Tracing splits compile time from execute time per (cfg, shape
         # bucket): the first call at a new frontier bucket pays the jit
         # trace+compile, later calls replay the cached executable. The
         # block_until_ready sync only happens while a tracer is active —
         # the untraced path keeps the async dispatch pipeline intact.
+        shape_key = (cfg, K, N, int(slot_node.shape[1]))
+        first = shape_key not in _stepped_shapes
+        _stepped_shapes.add(shape_key)
         if trace.enabled():
-            shape_key = (cfg, K, N, int(slot_node.shape[1]))
-            first = shape_key not in _stepped_shapes
-            _stepped_shapes.add(shape_key)
             with trace.span("grower_device/level_step", level=_level,
                             P=int(slot_node.shape[1]), compile=first,
                             candidates="sampled" if cfg.sample else "all"):

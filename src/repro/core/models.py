@@ -94,7 +94,12 @@ def prepare_train_data(learner, dataset, *, features: list[str] | None = None,
                 f"in the dataset. Available columns: {sorted(ds.spec.columns)}. "
                 "Solution: add the column, or point ranking_group= at it.")
         exclude.append(gcol)
-        groups = np.unique(np.asarray(ds.column(gcol)).astype(str),
+        # query ids from the RAW column where there is one: a categorical
+        # column's dictionary stops at max_vocab values and would merge the
+        # queries past it into one out-of-dictionary group
+        col = (ds.column(gcol) if isinstance(dataset, VerticalDataset)
+               else dataset[gcol])
+        groups = np.unique(np.asarray(col, dtype=object).astype(str),
                            return_inverse=True)[1].astype(np.int64)
     elif learner.task == Task.UPLIFT:
         tcol = getattr(learner.hparams, "treatment", "treatment")

@@ -12,7 +12,7 @@ from repro.tasks.ranking import (  # noqa: F401
     LambdaMARTLoss,
     group_aware_split,
     group_layout,
-    lambda_grad_batched,
+    lambda_grad_device,
     lambda_grad_naive,
 )
 from repro.tasks.uplift import UpliftTreesLearner  # noqa: F401

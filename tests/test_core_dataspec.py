@@ -31,6 +31,22 @@ def test_semantic_inference():
     assert spec.n_rows == 5
 
 
+def test_boolean_columns_bin_as_ordered_two_bin_features():
+    """A boolean (here MSLR's 0/1 boolean-model feature) bins as an ordered
+    feature split at 0.5, not as a category, so a table of numbers and
+    booleans stays on the device grower's fused kernel; a missing value
+    takes the majority."""
+    from repro.core.binning import bin_features
+    data = {"flag": np.array([1.0, 0.0, 1.0, 1.0, 0.0, None], dtype=object),
+            "x": np.array([0.5, 2.0, 3.5, 1.0, 7.0, 2.5], dtype=object)}
+    ds = dataset_from_raw(data)
+    assert ds.spec["flag"].semantic == Semantic.BOOLEAN
+    b = bin_features(ds, ["flag", "x"])
+    assert not b.is_cat.any()
+    assert list(b.codes[:, 0]) == [1, 0, 1, 1, 0, 1]
+    assert b.n_bins[0] == 2 and b.threshold_value(0, 1) == 0.5
+
+
 def test_user_override_wins_and_is_flagged():
     spec = infer_dataspec(_data(), semantics={"age": "CATEGORICAL"})
     assert spec["age"].semantic == Semantic.CATEGORICAL

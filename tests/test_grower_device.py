@@ -382,6 +382,36 @@ def test_device_engine_interpret_smoke(N, F, trees, block):
     assert (fd.n_nodes > 1).all(), "a tree did not grow"
 
 
+def test_every_frontier_width_compiles_with_the_first_tree():
+    """A first tree that stops at its root still compiles every frontier
+    width a tree of depth 4 can reach, so a later, full tree compiles no
+    level step (on the chip each such compile stalls training for
+    seconds between two trees)."""
+    from repro.obs import trace
+    N = 333                     # a row count no other test uses: fresh shapes
+    X, binned = _numerical_table(N, 3, seed=8)
+    gp = GrowthParams(max_depth=4, max_nodes=32,
+                      splitter=SplitterParams(stat_kind="gh", min_examples=5),
+                      engine="device")
+    leaf_fn = lambda s: np.array([-s[0] / (s[2] + 1e-12)], np.float32)
+    forest = empty_forest(2, 32, 1, feature_names=binned.names)
+    flat = np.stack([np.zeros(N), np.ones(N), np.ones(N), np.ones(N)], 1)
+    grow_tree(forest, 0, binned, X, flat, np.ones(N, bool), leaf_fn, gp,
+              np.random.default_rng(0))
+    assert forest.n_nodes[0] == 1                 # no gain: a root only
+    g = X[:, 0] + np.sin(3 * X[:, 1])
+    deep = np.stack([g, np.ones(N), np.ones(N), np.ones(N)], 1)
+    with trace.capture() as tr:
+        grow_tree(forest, 1, binned, X, deep, np.ones(N, bool), leaf_fn, gp,
+                  np.random.default_rng(0))
+    spans = [s for r in tr.roots for s in r.walk()]
+    assert forest.n_nodes[1] > 8                  # reached widths 2, 4, 8
+    steps = [s for s in spans if s.name == "grower_device/level_step"]
+    assert len(steps) == 4 and not any(s.args["compile"] for s in steps)
+    assert not [s for s in spans if s.name == "jax/compile"
+                and "step" in s.args.get("fun_name", "")]
+
+
 @pytest.mark.parametrize("sample", [False, True])
 def test_unsampled_level_step_gathers_no_candidate_codes(sample):
     """With every feature a candidate at every node, the jnp level step

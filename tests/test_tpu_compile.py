@@ -175,3 +175,72 @@ def test_forest_infer_kernel_compiles_for_v5e(one_chip):
                                                           jnp.float32)),
                       on_chip(tbl), on_chip(leaf), on_chip(p.block_depth),
                       kernel="forest_predict_pallas_tiled")
+
+
+# The ranking cell (MSLR-WEB30K cut to 2,400 queries): about 2^18 training
+# rows of 136 numerical features, 2,160 training queries of heavy-tailed size.
+MSLR_FEATURES = 136
+MSLR_QUERIES = 2160
+
+
+@pytest.mark.parametrize("n_slots", [8, 512])
+def test_fused_split_kernel_compiles_for_v5e_at_136_features(one_chip,
+                                                             n_slots):
+    """The split kernel at MSLR-WEB30K's 136 features, GBT's 4-stat
+    layout, at the narrowest frontier and the slot cap."""
+    from repro.kernels.histogram.fused import fused_split_pallas
+
+    def split(codes, stats, slot_of):
+        return fused_split_pallas(codes, stats, slot_of, n_slots, 256,
+                                  kind="gh", l2=0.0, min_examples=5)
+
+    _compile_for_chip(
+        split,
+        jax.ShapeDtypeStruct((N_ROWS, MSLR_FEATURES), jnp.int32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((N_ROWS, 4), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip),
+        kernel="fused_split_pallas")
+
+
+def test_unsampled_level_step_compiles_for_v5e_at_136_features(one_chip):
+    """The whole fused level step at 136 features, every feature a
+    candidate: the kernel reads the cached lane-major codes in place."""
+    step, args = _level_step_program("pallas", False, N_ROWS, MSLR_FEATURES,
+                                     K=1, P=32, sharding=one_chip)
+    with _no_persistent_cache():
+        text = step.lower(*args).compile().as_text()
+    assert "%fused_split_pallas" in text, "no fused split kernel"
+    assert max(_gather_sizes(text), default=0) < N_ROWS * MSLR_FEATURES
+
+
+def test_lambda_program_compiles_for_v5e(one_chip):
+    """The device lambda pass at the ranking cell's bucket ladder: query
+    sizes drawn as the cell draws them (lognormal(4.58, 0.64), clipped to
+    [1, 1251]) with one query at the published largest, 1,251 rows."""
+    from repro.tasks.ranking import _lambda_program, group_layout
+
+    rng = np.random.default_rng(0)
+    sizes = np.clip(np.rint(rng.lognormal(4.58, 0.64, MSLR_QUERIES)), 1,
+                    1251).astype(np.int64)
+    sizes[0] = 1251
+    layout = group_layout(np.repeat(np.arange(MSLR_QUERIES), sizes))
+    assert layout.widths[-1] == 2048
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    buckets = tuple((arg(b.index.shape, jnp.int32),
+                     arg(b.index.shape, jnp.bool_),
+                     arg(b.index.shape, jnp.float32),
+                     arg(b.index.shape[:1], jnp.float32))
+                    for b in layout.buckets)
+    n = layout.n_rows
+    with _no_persistent_cache():
+        compiled = _lambda_program().lower(
+            arg((n,), jnp.float32), buckets, arg((n,), jnp.int32),
+            k=5).compile()
+    mem = compiled.memory_analysis()
+    # the (G, k, m) pair blocks: tens of MB, where the (G, m, m) padded
+    # pass needed tens of GB
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
