@@ -5,8 +5,8 @@ shipped it to the host, and scanned gains in numpy — the kernel was off the
 critical path because the transfer dwarfed the accumulation (DESIGN.md §6).
 This kernel keeps the whole per-feature pipeline in VMEM:
 
-    1. accumulate hist[s, w, b] as one-hot MXU matmuls (histogram.py's
-       ``accumulate_tile``),
+    1. accumulate hist[s, w, b] as one exact bfloat16 one-hot MXU matmul
+       per example tile (histogram.py's ``accumulate_tile``),
     2. cumulative-sum the bins with an upper-triangular MXU matmul,
     3. score left/right partitions per split position (gh / class / moment
        stat layouts, §3.8), mask by min_examples,
@@ -28,8 +28,11 @@ well-defined read-modify-write). Examples run along lanes, as in
 histogram.py.
 
 VMEM per step (TN=512, W=512, B=256, S=4): hist scratch (S, W, B) 2 MiB,
-onehot_bin 512 KB, onehot_slot and its weighted copy 1 MiB each; the scan
-works on 128-slot row blocks (~1.5 MiB of temporaries). The slot axis is
+onehot_bin (B, TN) bf16 256 KB, and per MXU pass over a block of slots
+(histogram.py's ``slot_block``) a stacked LHS of three bfloat16 parts per
+statistic of at most ``LHS_ROWS`` = 768 rows, 1.5 MiB in f32 and 768 KB in
+bf16, and its product, 768 KB. The scan works on
+128-slot row blocks (~1.5 MiB of temporaries). The slot axis is
 padded to a multiple of 8 sublanes; the bin axis is the lane axis, so no
 minor axis is padded to 128 lanes.
 """
@@ -43,7 +46,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.histogram.histogram import (
-    _HIGHEST,
     TILE_N,
     _round_up,
     accumulate_tile,
@@ -52,6 +54,13 @@ from repro.kernels.histogram.histogram import (
 )
 
 NEG_INF = -1e30  # matches splitters.NEG_INF
+
+# The scan's cumulative-sum matmul multiplies f32 histogram sums, which the
+# MXU's default precision would round to bfloat16; HIGHEST keeps its
+# products exact. It runs once per feature per level, so its six passes cost
+# little. The per-tile accumulation splits its f32 operand exactly instead
+# (``histogram.split_bf16x3``) and runs at the default precision.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 # cephes logf: log(1 + f) = f - f^2 / 2 + f^3 * P(f) for f in
 # [sqrt(1/2) - 1, sqrt(2) - 1]; P's coefficients, highest power first

@@ -7,7 +7,15 @@ fast scatter, but they have a 128x128 systolic MXU. The TPU-native insight
     hist[s, n, b] = sum_i onehot_node[n, i] * stats[s, i] * onehot_bin[b, i]
                   = ((onehot_node * stats_s) @ onehot_bin^T)[n, b]
 
-i.e. S matmuls of (n_nodes, TN) @ (TN, B) per feature — fully MXU-resident.
+i.e. a (W, TN) @ (TN, B) matmul per statistic and feature, fully
+MXU-resident. All S of them run as one bfloat16 pass (``accumulate_tile``):
+the one-hot is exact in bfloat16, and each f32 statistic splits exactly into
+three bfloat16 parts (``split_bf16x3``), so one default-precision matmul with
+the 3·S·W part rows stacked as its LHS, accumulated in f32, forms the same
+exact products as a six-pass ``Precision.HIGHEST`` f32 dot per statistic.
+The MXU's stationary operand is then the (TN, B) one-hot, pushed once per
+tile instead of 6·S times. Where 3·S·W exceeds ``LHS_ROWS``, the pass runs
+once per block of slots (``slot_block``), which bounds its VMEM.
 
 Layout: examples run along the 128-wide lane axis. Codes, stats and node ids
 enter transposed — ``(F, 1, N)``, ``(S, N)``, ``(1, N)`` — so every block is
@@ -20,8 +28,11 @@ block (revisited across the trailing grid dim; TPU grid steps are sequential,
 so read-modify-write on out_ref is well-defined).
 
 VMEM per step (TN=512, B=256, S=4, n_nodes=32): codes + slots 4KB, stats
-8KB, onehot_bin (B, TN) 512KB, onehot_node (nodes, TN) 64KB, out block
-(S, nodes, B) 128KB double-buffered -> ~1 MB.
+8KB, onehot_bin (B, TN) bf16 256KB, the stacked LHS (3·S·nodes, TN) 768KB
+in f32 and 384KB in bf16, its product (3·S·nodes, B) 384KB, out block
+(S, nodes, B) 128KB double-buffered -> ~2 MB. A pass's LHS and product stay
+under ~3 MB at any width; the double-buffered out block grows with S·nodes
+and alone fills the 16 MiB of scoped VMEM at 2048 nodes and S=4.
 """
 from __future__ import annotations
 
@@ -31,31 +42,71 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# The stats operand is f32 data (gradients, weights): the MXU's default
-# precision would round it to bfloat16. HIGHEST keeps the products exact
-# (the one-hot operand is exact in any precision).
-_HIGHEST = jax.lax.Precision.HIGHEST
-
 
 def _round_up(x: int, m: int) -> int:
     return -(-int(x) // m) * m
+
+
+def split_bf16x3(x):
+    """f32 ``x`` -> bfloat16 ``(hi, mid, lo)`` with hi + mid + lo == x
+    exactly: each part rounds what the ones before it leave, and three
+    8-bit significands hold the 24 of a float32. Products of the parts with
+    a 0/1 operand are exact in the MXU's f32 accumulator."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+# Rows of the stacked bfloat16 LHS in one MXU pass. A wide frontier or many
+# statistics run as several passes, each over a block of slots, so that one
+# pass's LHS (in f32 and in bf16) and its f32 product take about 3 MiB of
+# VMEM at TN=512, B=256, whatever S and W are.
+LHS_ROWS = 768
+
+
+def slot_block(n_stats: int, n_slots: int) -> int:
+    """Slots per MXU pass: a multiple of 8 with 3·S·block <= LHS_ROWS (8 at
+    the least), or all ``n_slots`` where they fit."""
+    return min(n_slots, max(8, LHS_ROWS // (3 * n_stats) // 8 * 8))
 
 
 def accumulate_tile(codes, slot, stats, acc_ref, *, n_slots: int,
                     n_bins: int):
     """acc_ref[s, w, b] += sum of stats[s, i] over the tile's examples i with
     slot[i] == w and codes[i] == b. codes/slot: (1, TN) int32 (slot -1 =
-    inactive, never matches); stats: (S, TN) f32; acc_ref: (S, W, B)."""
+    inactive, never matches); stats: (S, TN) f32; acc_ref: (S, W, B), W a
+    multiple of 8.
+
+    One MXU pass per block of ``slot_block(S, W)`` slots, for all S
+    statistics: the LHS stacks, for each of the three bfloat16 parts of each
+    statistic, the (block, TN) rows that hold the part where the example's
+    slot is w and 0 elsewhere; the RHS is the exact bfloat16 one-hot of the
+    bins. The products are exact and accumulate in f32, as a
+    ``Precision.HIGHEST`` f32 dot's do; only the order of the f32 additions
+    differs."""
     TN = codes.shape[-1]
+    n_stats = acc_ref.shape[0]
     onehot_bin = (jax.lax.broadcasted_iota(jnp.int32, (n_bins, TN), 0)
-                  == codes).astype(jnp.float32)                 # (B, TN)
-    onehot_slot = (jax.lax.broadcasted_iota(jnp.int32, (n_slots, TN), 0)
-                   == slot).astype(jnp.float32)                 # (W, TN)
-    for s in range(acc_ref.shape[0]):
-        acc_ref[s] += jax.lax.dot_general(
-            onehot_slot * stats[s:s + 1, :], onehot_bin,
-            (((1,), (1,)), ((), ())), precision=_HIGHEST,
-            preferred_element_type=jnp.float32)                 # (W, B) MXU
+                  == codes).astype(jnp.bfloat16)                # (B, TN)
+    parts = [p.astype(jnp.float32) for p in split_bf16x3(stats)]  # (S, TN)
+    Wb = slot_block(n_stats, n_slots)
+    for w0 in range(0, n_slots, Wb):
+        wb = min(Wb, n_slots - w0)
+        in_slot = (jax.lax.broadcasted_iota(jnp.int32, (wb, TN), 0)
+                   == slot - w0)                                # (wb, TN)
+        lhs = jnp.concatenate(
+            [jnp.where(in_slot, p[s:s + 1, :], 0.0)
+             for s in range(n_stats) for p in parts],
+            axis=0).astype(jnp.bfloat16)                       # (3S·wb, TN)
+        out = jax.lax.dot_general(
+            lhs, onehot_bin, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                # (3S·wb, B)
+        block = [out[r:r + wb] for r in range(0, out.shape[0], wb)]
+        for s in range(n_stats):
+            acc_ref[s, w0:w0 + wb] += ((block[3 * s] + block[3 * s + 1])
+                                       + block[3 * s + 2])
 
 
 TILE_N = 512      # example tile (lanes) of both kernels

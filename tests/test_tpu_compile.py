@@ -67,14 +67,17 @@ def _compile_for_chip(fn, *args, kernel=None):
 
 
 @pytest.mark.parametrize("n_stats,kind", [(2, "gh"), (3, "class"),
-                                           (4, "gh")])
+                                           (4, "gh"), (11, "class")])
 @pytest.mark.parametrize("n_slots", [8, 512])
 def test_fused_split_kernel_compiles_for_v5e(one_chip, n_slots, n_stats,
                                               kind):
     """The device grower's split kernel at the frontier slot cap
-    (grower_device._W_CAP) and the narrowest frontier, for 2 to 4 stats:
-    GBT's 4-stat gh layout, and the binary class layout, whose entropy
-    score runs ``log_f32`` inside the kernel."""
+    (grower_device._W_CAP) and the narrowest frontier, for 2 to 11 stats:
+    GBT's 4-stat gh layout, and the class layout of 2 and of 10 classes,
+    whose entropy score runs ``log_f32`` inside the kernel. At 11 stats and
+    512 slots the per-tile MXU passes run over blocks of slots
+    (``histogram.slot_block``); one pass over all 16,896 part rows would
+    not fit the scoped VMEM."""
     from repro.kernels.histogram.fused import fused_split_pallas
 
     def split(codes, stats, slot_of):
@@ -117,6 +120,31 @@ def test_histogram_kernel_compiles_for_v5e(one_chip):
         jax.ShapeDtypeStruct((N_ROWS, N_FEATURES), jnp.uint8,
                              sharding=one_chip),
         jax.ShapeDtypeStruct((N_ROWS, 4), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip),
+        kernel="histogram_pallas")
+
+
+@pytest.mark.parametrize("n_nodes,n_stats", [(1024, 3), (1024, 4),
+                                             (2048, 3)])
+def test_histogram_kernel_compiles_for_v5e_at_wide_frontiers(one_chip,
+                                                             n_nodes,
+                                                             n_stats):
+    """The batched grower's histogram backend at the wide frontiers of deep
+    trees (the backend pads the frontier to a power of two): the per-tile
+    MXU passes run over blocks of nodes, so their VMEM stays ~3 MiB beside
+    the double-buffered (S, nodes, B) output block. At 2048 nodes and 4
+    stats that block alone takes the 16 MiB of scoped VMEM."""
+    from repro.kernels.histogram.histogram import histogram_pallas
+
+    def hist(codes, stats, node_of):
+        return histogram_pallas(codes, stats, node_of, n_nodes, 256)
+
+    _compile_for_chip(
+        hist,
+        jax.ShapeDtypeStruct((N_ROWS, N_FEATURES), jnp.uint8,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((N_ROWS, n_stats), jnp.float32,
+                             sharding=one_chip),
         jax.ShapeDtypeStruct((N_ROWS,), jnp.int32, sharding=one_chip),
         kernel="histogram_pallas")
 
