@@ -22,7 +22,6 @@
                     vs offered QPS, clean vs fault-injected
                     (BENCH_serve.json when run as a module; --quick here)
   distributed_df  — §3.9 traffic scaling
-  roofline_report — assignment §Roofline/§Dry-run tables (from results/)
 """
 from __future__ import annotations
 
@@ -127,20 +126,6 @@ def main() -> None:
         out = accuracy_rank.run(verbose=False)
         for n, r in sorted(out["mean_rank"].items(), key=lambda kv: kv[1]):
             print(f"  rank {r:5.2f}  {n}  [train {out['train_time_s'][n]:.1f}s]")
-    if "roofline" not in args.skip:
-        try:
-            from benchmarks import roofline_report
-            cells = roofline_report.load_cells()
-            if cells:
-                print(f"== roofline ({len(cells)} unrolled cells; full table in "
-                      "EXPERIMENTS.md) ==", flush=True)
-                worst = sorted(cells, key=lambda d: d["terms"]["roofline_fraction"])
-                for d in worst[:3] + worst[-3:]:
-                    t = d["terms"]
-                    print(f"  {d['arch']:16s} {d['shape']:12s} dominant={t['dominant']:10s} "
-                          f"roofline_frac={t['roofline_fraction']:.3f}")
-        except Exception as e:
-            print(f"  (roofline artifacts unavailable: {e})")
     print(f"\nall benchmarks done in {time.time() - t_all:.0f}s")
 
 

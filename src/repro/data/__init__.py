@@ -1,2 +1,2 @@
-"""Data pipelines: synthetic tabular suites (OpenML stand-ins), token streams
-for LM training, and file readers/writers."""
+"""Data pipelines: synthetic tabular suites (OpenML stand-ins) and file
+readers/writers."""

@@ -1,9 +1,3 @@
-from repro.serving.decode import (  # noqa: F401
-    ServeBundle,
-    make_decode_step,
-    make_prefill,
-    serve_state_specs,
-)
 from repro.serving.forest import (  # noqa: F401
     ForestServeBundle,
     MicroBatcher,

@@ -1,8 +1,7 @@
 """Micro-batched decision-forest serving (DESIGN.md §5.4).
 
-Mirrors the ServeBundle shape of ``serving/decode.py`` for forests: a
-factory wraps a model's CompiledPredictor (§5.1) into a frozen bundle whose
-dispatches are padded to a fixed ladder of batch-size buckets — jit'd
+A factory wraps a model's CompiledPredictor (§5.1) into a frozen bundle
+whose dispatches are padded to a fixed ladder of batch-size buckets — jit'd
 engines then trace one program per bucket instead of one per ragged request
 size. ``MicroBatcher`` is the request loop on top: accumulate requests
 (encoding each on arrival, off the dispatch path), pad the concatenated

@@ -418,7 +418,7 @@ def test_unsampled_level_step_gathers_no_candidate_codes(sample):
     reads the codes in place: its compiled CPU program holds no gather of
     N·F elements. With per-node sampling the per-example (N, kf) candidate
     gather is still there."""
-    from conftest import _gather_sizes, _level_step_program
+    from forest_helpers import _gather_sizes, _level_step_program
     from repro.core.sampling import sample_size
 
     N, F = 16384, 28

@@ -76,7 +76,7 @@ def test_traversal_strategy_equivalence_sweep(n, trees, n_feats, out_dim,
     multi-output leaves) and hostile batches (0 rows, 1 row, NaN/±inf on
     numerical columns, unseen/negative category codes), every strategy's
     per-tree output is bit-identical to the vectorized engine."""
-    from conftest import _make_random_forest
+    from forest_helpers import _make_random_forest
     from repro.core.tree import (LEAF_PATH_BUDGET, compile_predict_raw,
                                  leaf_path_sizes, predict_naive)
     from repro.kernels.forest_infer.ops import forest_predict_bucketed

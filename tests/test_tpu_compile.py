@@ -20,7 +20,8 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from conftest import _gather_sizes, _level_step_program, _make_random_forest
+from forest_helpers import (_gather_sizes, _level_step_program,
+                            _make_random_forest)
 
 N_ROWS = 1 << 18           # HIGGS-shaped training set cut to one chip
 N_FEATURES = 28

@@ -41,7 +41,7 @@ from repro.core.tree import (
 )
 from repro.kernels.forest_infer.ops import forest_predict_bucketed
 
-from conftest import _make_random_forest
+from forest_helpers import _make_random_forest
 
 pytestmark = pytest.mark.traversal
 
