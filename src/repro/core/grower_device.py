@@ -47,6 +47,16 @@ from repro.core.tree import MASK_WORDS, Forest
 
 _B = 256          # bin axis (uint8 codes)
 _W_CAP = 512      # per-chunk slot width inside the level step
+# Widest frontier at which routing reads each example's per-slot values by a
+# one-hot over the P slots (P compare-selects per example and value) rather
+# than by gathers: for numerical splits, and with category masks (eight more
+# values per slot). Each is where the two cross, timing `_route` alone on a
+# TPU v5e at N = 944,128, F = 28, one tree (median of 10 calls, one-hot vs
+# gather): numerical 1.26 vs 1.87 ms at P = 32, 11.0 vs 14.9 at 8,192, 20.7
+# vs 14.9 at 16,384; with masks 1.56 vs 7.31 ms at 32, 12.9 vs 22.1 at
+# 2,048, 24.6 vs 22.2 at 4,096.
+_DENSE_ROUTING_MAX_P = 8192
+_DENSE_ROUTING_MAX_P_CAT = 2048
 
 # (cfg, K, N, P) shape buckets whose level step has already been jitted in
 # this process — lets the grower compile every frontier width with the
@@ -264,7 +274,8 @@ def _level_step(cfg: _StepConfig):
                 iscat[fsel_c], jwin[:, :, None], axis=2)[:, :, 0]
         sbin = jnp.where(iscat_w, 0, bwin + 1)
 
-        # go-right-by-code table for routing + the forest's category mask
+        # go-right-by-code table: the forest's category mask, which also
+        # routes the examples of categorical steps
         bins = jnp.arange(_B)[None, None, :]
         tbl_num = bins >= sbin[:, :, None]
         if cfg.cat_mode == "none":
@@ -287,7 +298,6 @@ def _level_step(cfg: _StepConfig):
              feat_a, sbin_a, catm_a, left_a, gain_a, lstats_a, nn, node_of,
              depth):
         K, P = slot_node.shape
-        N = codes.shape[0]
         karange = jnp.arange(K)[:, None]
 
         # 1. candidate features per (tree, slot), keyed by (tree, node id)
@@ -323,7 +333,7 @@ def _level_step(cfg: _StepConfig):
             valid &= nn[:, None] + 2 * (rank + 1) <= cfg.max_nodes
             left_id = jnp.where(valid, nn[:, None] + 2 * rank, -1)
             nv = valid.sum(axis=1).astype(jnp.int32)
-            nn = nn + 2 * nv
+            first, nn = nn, nn + 2 * nv                       # first child ids
             depth = depth + (nv > 0)
 
             # 4. write the chosen conditions into the device forest arrays
@@ -341,15 +351,9 @@ def _level_step(cfg: _StepConfig):
 
         # 5. route every example of a split slot to its child
         with jax.named_scope("routing"):
-            slotc = jnp.maximum(slot_of, 0)
-            route = (slot_of >= 0) & jnp.take_along_axis(valid, slotc, axis=1)
-            f_ex = jnp.take_along_axis(feat_w, slotc, axis=1)     # (K, N)
-            c_ex = codes[jnp.arange(N)[None, :], f_ex]
-            go = tbl[karange, slotc, c_ex]
-            l_ex = jnp.take_along_axis(left_id, slotc, axis=1)
-            node_of = jnp.where(route, l_ex + go, node_of)
-            r_ex = jnp.take_along_axis(rank, slotc, axis=1)
-            slot_of = jnp.where(route, 2 * r_ex + go, -1)
+            node_of, slot_of = _route(
+                slot_of, node_of, first, valid, rank, feat_w, sbin_w,
+                None if cfg.cat_mode == "none" else packed, codes, codes_t)
 
         # 6. child stats in one segment-sum; new frontier = compacted children
         with jax.named_scope("child_stats"):
@@ -370,6 +374,78 @@ def _level_step(cfg: _StepConfig):
                 lstats_a, nn, node_of, depth, nv)
 
     return step
+
+
+def _routing(P: int, cat: bool) -> str:
+    """How the level step reads each example's per-slot values at frontier
+    width P, with category masks when ``cat``: "dense" (a one-hot over the P
+    slots) or "gather"."""
+    limit = _DENSE_ROUTING_MAX_P_CAT if cat else _DENSE_ROUTING_MAX_P
+    return "dense" if P <= limit else "gather"
+
+
+def _route(slot_of, node_of, first, valid, rank, feat, sbin, masks, codes,
+           codes_t):
+    """Route every example of a split slot to its child, with no gather of
+    per-example values up to frontier width ``_DENSE_ROUTING_MAX_P``
+    (``_DENSE_ROUTING_MAX_P_CAT`` with category masks).
+
+    slot_of, node_of (K, N) i32: each example's frontier slot (-1: none) and
+    node. Per slot (K, P): valid bool, rank i32 (order among the split
+    slots), feat and sbin i32. first (K,) i32: the id of the level's first
+    child, so slot p splits into first + 2 * rank[p] and the node after.
+    masks (K, P, MASK_WORDS) u32: bit b of word w says whether code
+    32 w + b goes right; None when every split is numerical (code >= sbin
+    goes right). codes (N, F) i32, or codes_t in the (F, 1, Np) lane-major
+    layout when the step has it. Returns the new node_of and slot_of
+    (2 * rank + go; -1 for examples whose slot did not split).
+    """
+    import jax.numpy as jnp
+
+    K, P = valid.shape
+    N, F = codes.shape
+    dense = _routing(P, masks is not None) == "dense"
+    if dense:
+        hit = slot_of[:, None, :] == jnp.arange(P, dtype=jnp.int32)[:, None]
+
+        def at_slot(table):                          # (K, P) -> (K, N)
+            return jnp.where(hit, table[..., None], 0).sum(axis=1,
+                                                         dtype=table.dtype)
+    else:
+        slotc = jnp.maximum(slot_of, 0)
+
+        def at_slot(table):
+            got = jnp.take_along_axis(table, slotc, axis=1)
+            return jnp.where(slot_of >= 0, got, 0)
+
+    key = at_slot(jnp.where(valid, rank + 1, 0))  # 0: the example stays
+    # split feature and bin in one value (sbin <= _B)
+    split = at_slot(feat * (2 * _B) + sbin)
+    f_ex = split // (2 * _B)
+    # the example's code at its split feature: one pass over the table
+    fs = jnp.arange(F, dtype=jnp.int32)
+    if codes_t is not None:
+        code = jnp.where(f_ex[:, None, :] == fs[:, None],
+                         codes_t[None, :, 0, :N], 0).sum(axis=1)
+    else:
+        code = jnp.where(f_ex[:, :, None] == fs, codes[None], 0).sum(axis=2)
+    if masks is None:
+        go = (code >= split % (2 * _B)).astype(jnp.int32)
+    else:
+        word_of = code >> 5
+        if dense:
+            # one (K, P, N) select per word: selecting all words at once
+            # keeps the (K, P, N) one-hot in memory on the v5e (P bytes per
+            # example from P = 128)
+            word = jnp.zeros(code.shape, masks.dtype)
+            for w in range(masks.shape[2]):
+                word = jnp.where(word_of == w, at_slot(masks[:, :, w]), word)
+        else:
+            word = masks[jnp.arange(K)[:, None], slotc, word_of]
+        go = ((word >> (code & 31).astype(jnp.uint32)) & 1).astype(jnp.int32)
+    child = 2 * (key - 1) + go
+    return (jnp.where(key > 0, first[:, None] + child, node_of),
+            jnp.where(key > 0, child, -1))
 
 
 def _next_pow2(x: int) -> int:
@@ -483,7 +559,9 @@ def grow_trees_device(forest: Forest, ts, binned: BinnedFeatures,
         if trace.enabled():
             with trace.span("grower_device/level_step", level=_level,
                             P=int(slot_node.shape[1]), compile=first,
-                            candidates="sampled" if cfg.sample else "all"):
+                            candidates="sampled" if cfg.sample else "all",
+                            routing=_routing(int(slot_node.shape[1]),
+                                             cfg.cat_mode != "none")):
                 out = step(
                     codes, codes_t, nbins, iscat, stats, tree_ids, slot_of,
                     slot_node, feat_a, sbin_a, catm_a, left_a, gain_a,

@@ -53,11 +53,13 @@ def _make_random_forest(n_trees, n_splits_list, n_features, out_dim=1,
 
 # ------------------------------- device grower's level step, lowered alone
 
-def _level_step_program(impl, sample, N, F, K=1, P=32, sharding=None):
-    """The device grower's jitted level step for a numerical GBT table of
-    ``N`` x ``F`` codes, with ``K`` trees and ``P`` frontier slots, and the
-    shapes of its arguments (placed on ``sharding`` when given). ``sample``
-    keeps half the features per node."""
+def _level_step_program(impl, sample, N, F, K=1, P=32, sharding=None,
+                        cat_mode="none"):
+    """The device grower's jitted level step for a GBT table of ``N`` x ``F``
+    codes, with ``K`` trees and ``P`` frontier slots, and the shapes of its
+    arguments (placed on ``sharding`` when given). ``sample`` keeps half the
+    features per node; ``cat_mode`` other than "none" ("cart", "onehot")
+    builds the jnp step's categorical scans."""
     import jax
     import jax.numpy as jnp
 
@@ -68,7 +70,7 @@ def _level_step_program(impl, sample, N, F, K=1, P=32, sharding=None):
 
     S, M = 4, 127
     cfg = _StepConfig(kind="gh", l2=0.0, min_examples=5, min_gain=0.0,
-                      cat_mode="none", sample=sample, sampling_key=7,
+                      cat_mode=cat_mode, sample=sample, sampling_key=7,
                       kf=sample_size(0.5, F) if sample else F, F=F, S=S,
                       M=M, max_nodes=M, impl=impl)
 

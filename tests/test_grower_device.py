@@ -408,26 +408,111 @@ def test_every_frontier_width_compiles_with_the_first_tree():
     assert forest.n_nodes[1] > 8                  # reached widths 2, 4, 8
     steps = [s for s in spans if s.name == "grower_device/level_step"]
     assert len(steps) == 4 and not any(s.args["compile"] for s in steps)
+    assert all(s.args["routing"] == "dense" for s in steps)
     assert not [s for s in spans if s.name == "jax/compile"
                 and "step" in s.args.get("fun_name", "")]
 
 
-@pytest.mark.parametrize("sample", [False, True])
-def test_unsampled_level_step_gathers_no_candidate_codes(sample):
-    """With every feature a candidate at every node, the jnp level step
-    reads the codes in place: its compiled CPU program holds no gather of
-    N·F elements. With per-node sampling the per-example (N, kf) candidate
-    gather is still there."""
+@pytest.mark.parametrize("impl,cat_mode,sample", [
+    pytest.param("jnp", "none", False, id="False"),
+    pytest.param("jnp", "none", True, id="True"),
+    ("interpret", "none", False), ("jnp", "cart", False)])
+def test_unsampled_level_step_gathers_no_candidate_codes(impl, cat_mode,
+                                                         sample):
+    """With every feature a candidate at every node, the level step reads
+    the codes in place, and routing reads each example's slot values by a
+    one-hot over the frontier slots, its code by a select over features and
+    its go-right bit from its slot's split bin or category mask: the
+    compiled CPU program holds no gather of N or more elements. (The CART
+    scan's gather of the (K, P, F, B, S) histogram in category order,
+    917,504 elements here, is per bin and under N.) With per-node sampling
+    the per-example (N, kf) candidate gather is still there."""
     from forest_helpers import _gather_sizes, _level_step_program
     from repro.core.sampling import sample_size
 
-    N, F = 16384, 28
-    step, args = _level_step_program("jnp", sample, N, F)
+    N, F = 1 << 20, 28
+    step, args = _level_step_program(impl, sample, N, F, P=32,
+                                     cat_mode=cat_mode)
     sizes = _gather_sizes(step.lower(*args).compile().as_text())
     if sample:
         assert N * sample_size(0.5, F) in sizes, sizes
     else:
-        assert sizes and max(sizes) < N * F, sizes
+        assert max(sizes, default=0) < N, sizes
+
+
+def _route_reference(slot_of, node_of, first, valid, rank, feat, sbin,
+                     masks, codes):
+    """The level step's routing as per-example gathers (numpy): each
+    example's slot, then its code at the slot's split feature, then the
+    slot's go-right-by-code table at that code."""
+    K, N = slot_of.shape
+    slotc = np.maximum(slot_of, 0)
+    kk = np.arange(K)[:, None]
+    route = (slot_of >= 0) & valid[kk, slotc]
+    code = codes[np.arange(N)[None, :], feat[kk, slotc]]
+    if masks is None:
+        tbl = np.arange(256)[None, None, :] >= sbin[:, :, None]
+    else:
+        b = np.arange(256)
+        tbl = (masks[:, :, b >> 5] >> (b & 31).astype(np.uint32)) & 1
+    go = tbl[kk, slotc, code].astype(np.int32)
+    left_id = np.where(valid, first[:, None] + 2 * rank, -1)
+    return (np.where(route, left_id[kk, slotc] + go, node_of),
+            np.where(route, 2 * rank[kk, slotc] + go, -1))
+
+
+@pytest.mark.parametrize("layout", ["codes", "lane_major"])
+@pytest.mark.parametrize("cat", [False, True])
+@pytest.mark.parametrize("P", [1, 32, "widest_dense", "gather"])
+def test_routing_matches_per_example_gathers(P, cat, layout):
+    """The level step's routing gives the same child node and slot, to the
+    bit, as per-example gathers: three trees, slots of -1, slots that do not
+    split, codes 0 and 255, numerical and categorical splits, on both sides
+    of the frontier width where routing turns from dense to gather (the
+    widest dense frontier and twice it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import grower_device as gd
+    from repro.core.tree import MASK_WORDS
+    from repro.kernels.histogram.histogram import lane_major_codes
+
+    widest = gd._DENSE_ROUTING_MAX_P_CAT if cat else gd._DENSE_ROUTING_MAX_P
+    P = {"widest_dense": widest, "gather": 2 * widest}.get(P, P)
+    assert gd._routing(P, cat) == ("gather" if P > widest else "dense")
+    K, N, F = 3, 1100, 5
+    rng = np.random.default_rng(P * 4 + 2 * cat + (layout == "codes"))
+    codes = rng.integers(0, 256, size=(N, F)).astype(np.int32)
+    codes[:8] = 0
+    codes[8:16] = 255
+    slot_of = rng.integers(-1, P, size=(K, N)).astype(np.int32)
+    slot_of[:, :4] = -1
+    valid = rng.random((K, P)) < 0.7
+    valid[:, 0] = True
+    if P > 1:
+        valid[:, 1] = False
+    vi = valid.astype(np.int32)
+    rank = (np.cumsum(vi, axis=1) - vi).astype(np.int32)
+    feat = rng.integers(0, F, size=(K, P)).astype(np.int32)
+    sbin = rng.integers(0, 257, size=(K, P)).astype(np.int32)
+    sbin[:, 0] = 256                        # code 255 goes left
+    masks = (rng.integers(0, 2 ** 32, size=(K, P, MASK_WORDS),
+                          dtype=np.uint64).astype(np.uint32) if cat else None)
+    node_of = rng.integers(0, 50, size=(K, N)).astype(np.int32)
+    first = rng.integers(1, 100, size=K).astype(np.int32)
+
+    codes_d = jnp.asarray(codes)
+    codes_t = lane_major_codes(codes_d) if layout == "lane_major" else None
+    node_d, slot_d = jax.jit(gd._route)(
+        jnp.asarray(slot_of), jnp.asarray(node_of), jnp.asarray(first),
+        jnp.asarray(valid), jnp.asarray(rank), jnp.asarray(feat),
+        jnp.asarray(sbin), None if masks is None else jnp.asarray(masks),
+        codes_d, codes_t)
+    node_r, slot_r = _route_reference(slot_of, node_of, first, valid, rank,
+                                      feat, sbin, masks, codes)
+    np.testing.assert_array_equal(np.asarray(slot_d), slot_r)
+    np.testing.assert_array_equal(np.asarray(node_d), node_r)
+    assert (slot_r >= 0).any() and (slot_r == -1).any()
 
 
 def test_resolve_engine_reports_fallback():

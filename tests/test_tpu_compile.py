@@ -98,15 +98,16 @@ def test_fused_split_kernel_compiles_for_v5e(one_chip, n_slots, n_stats,
 def test_unsampled_level_step_compiles_for_v5e(one_chip):
     """The device grower's whole level step on the fused kernel, every
     feature a candidate (GBT's default): one tree, 32 frontier slots. The
-    kernel reads the cached lane-major codes, so the program gathers no
-    (1, N, F) candidate codes."""
+    kernel reads the cached lane-major codes, and routing reads them by a
+    select over features and each slot's values by a one-hot over the
+    slots, so the program gathers nothing per example."""
     step, args = _level_step_program("pallas", False, N_ROWS, N_FEATURES,
                                      K=1, P=32, sharding=one_chip)
     with _no_persistent_cache():
         text = step.lower(*args).compile().as_text()
     assert "%fused_split_pallas" in text, "no fused split kernel"
     sizes = _gather_sizes(text)
-    assert max(sizes, default=0) < N_ROWS * N_FEATURES, sizes
+    assert max(sizes, default=0) < N_ROWS, sizes
 
 
 def test_histogram_kernel_compiles_for_v5e(one_chip):
@@ -234,13 +235,15 @@ def test_fused_split_kernel_compiles_for_v5e_at_136_features(one_chip,
 
 def test_unsampled_level_step_compiles_for_v5e_at_136_features(one_chip):
     """The whole fused level step at 136 features, every feature a
-    candidate: the kernel reads the cached lane-major codes in place."""
+    candidate: the kernel reads the cached lane-major codes in place, and
+    routing gathers nothing per example."""
     step, args = _level_step_program("pallas", False, N_ROWS, MSLR_FEATURES,
                                      K=1, P=32, sharding=one_chip)
     with _no_persistent_cache():
         text = step.lower(*args).compile().as_text()
     assert "%fused_split_pallas" in text, "no fused split kernel"
-    assert max(_gather_sizes(text), default=0) < N_ROWS * MSLR_FEATURES
+    sizes = _gather_sizes(text)
+    assert max(sizes, default=0) < N_ROWS, sizes
 
 
 def test_lambda_program_compiles_for_v5e(one_chip):
